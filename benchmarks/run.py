@@ -47,6 +47,8 @@ def main() -> int:
         argv = [a for a in argv if a != "--dry-run"]
         print("# dry-run: collection-test scale, numbers not meaningful")
     picked = argv or BENCHES
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     all_rows = []
     failed = []
     print("name,us_per_call,derived")

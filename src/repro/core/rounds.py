@@ -59,7 +59,8 @@ from repro.models.common import NO_SHARDING, ShardingPolicy
 from repro.models.model import Model
 from repro.optim import ErrorFeedback, int8_dequantize, int8_quantize, \
     make_optimizer
-from repro.runtime.sharding import constrain_client_batch, constrain_state
+from repro.runtime.sharding import (constrain_client_batch, constrain_state,
+                                    under_mesh)
 
 Params = Dict[str, Any]
 
@@ -347,6 +348,7 @@ def make_train_step(model: Model, *, policy: ShardingPolicy = NO_SHARDING,
         metrics["total"] = total
         return constrain_state(new_state, mesh), metrics
 
+    step = under_mesh(step, mesh)
     if jit:
         return jax.jit(step, donate_argnums=(1,))
     return step
@@ -537,6 +539,7 @@ def _make_local_steps_step(model: Model, opt, smasher, *, policy, remat,
             new_state["smashed_ef"] = new_sm_ef
         return constrain_state(new_state, mesh), metrics
 
+    step = under_mesh(step, mesh)
     if jit:
         return jax.jit(step, donate_argnums=(1,))
     return step
@@ -683,6 +686,7 @@ def _make_async_step(model: Model, opt, smasher, *, policy, remat,
         metrics["aggregated"] = aggregate
         return constrain_state(new_state, mesh), metrics
 
+    step = under_mesh(step, mesh)
     if jit:
         return jax.jit(step, donate_argnums=(1,))
     return step
@@ -704,6 +708,7 @@ def make_eval_step(model: Model, *, policy: ShardingPolicy = NO_SHARDING,
                                        per_client=True)
         return per_loss, metrics
 
+    step = under_mesh(step, policy.mesh)
     return jax.jit(step) if jit else step
 
 

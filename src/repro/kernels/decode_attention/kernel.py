@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import compiler_params
+from repro.kernels import mxu
 
 NEG_INF = -1e30
 DEFAULT_BS = 512
@@ -52,7 +52,9 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # (group, hd)
         k = k_ref[0, 0].astype(jnp.float32)                # (bs, hd)
+        prec = mxu.precision(q_ref.dtype)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=prec,
                                 preferred_element_type=jnp.float32)
         pos = s_start + jax.lax.broadcasted_iota(jnp.int32, (group, bs), 1)
         valid = pos < clen
@@ -66,7 +68,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p.astype(v_ref.dtype), v_ref[0, 0],
+            p.astype(v_ref.dtype), v_ref[0, 0], precision=prec,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -104,7 +106,9 @@ def _paged_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # (group, hd)
         k = k_ref[0, 0].astype(jnp.float32)                # (ps, hd)
+        prec = mxu.precision(q_ref.dtype)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=prec,
                                 preferred_element_type=jnp.float32)
         pos = s_start + jax.lax.broadcasted_iota(jnp.int32, (group, ps), 1)
         valid = pos < clen
@@ -118,7 +122,7 @@ def _paged_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p.astype(v_ref.dtype), v_ref[0, 0],
+            p.astype(v_ref.dtype), v_ref[0, 0], precision=prec,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -174,7 +178,7 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, page_table, cache_len,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kvh, group, hd), q.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -221,7 +225,7 @@ def decode_attention_pallas(q, k, v, cache_len, *, scale: float | None = None,
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, hd), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

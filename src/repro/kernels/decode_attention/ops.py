@@ -17,10 +17,7 @@ from repro.kernels.decode_attention.kernel import (decode_attention_paged_pallas
 def _use_pallas() -> bool:
     if os.environ.get("REPRO_PALLAS_INTERPRET") == "1":
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def decode_attention(q, k, v, cache_len, *, scale: Optional[float] = None,

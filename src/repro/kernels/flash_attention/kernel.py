@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import compiler_params
+from repro.kernels import mxu
 
 NEG_INF = -1e30
 
@@ -117,7 +117,9 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _body():
         q = q_ref[0].astype(jnp.float32) * scale
         k = k_ref[0, 0].astype(jnp.float32)
+        prec = mxu.precision(q_ref.dtype)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=prec,
                                 preferred_element_type=jnp.float32)
         mask = _pair_mask(q_start, k_start, causal=causal, window=window,
                           bq=bq, bk=bk)
@@ -130,7 +132,7 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p.astype(v_ref.dtype), v_ref[0, 0],
+            p.astype(v_ref.dtype), v_ref[0, 0], precision=prec,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -202,7 +204,7 @@ def flash_attention_pallas(q, k, v, q_offset=0, *, causal: bool = True,
             pltpu.VMEM((bq, 1), jnp.float32),     # normalizer
             pltpu.VMEM((bq, hd), jnp.float32),    # output accumulator
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -222,13 +224,15 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    prec = mxu.precision(q_ref.dtype)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=prec,
                             preferred_element_type=jnp.float32)
     mask = _pair_mask(q_start, k_start, causal=causal, window=window,
                       bq=bq, bk=bk)
     s = jnp.where(mask, s, NEG_INF)
     p = jnp.exp(s - lse_ref[0])                          # (bq, bk)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             precision=prec,
                              preferred_element_type=jnp.float32)
     ds = p * (dp - delta_ref[0]) * scale
     return p, ds, do
@@ -255,6 +259,7 @@ def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                              delta_ref, q_start, k_start, scale=scale,
                              causal=causal, window=window, bq=bq, bk=bk)
         dq_acc[...] += jax.lax.dot(ds, k_ref[0, 0].astype(jnp.float32),
+                                   precision=mxu.precision(q_ref.dtype),
                                    preferred_element_type=jnp.float32)
 
     @pl.when(ik == n_kv - 1)
@@ -286,12 +291,13 @@ def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                               delta_ref, q_start, k_start, scale=scale,
                               causal=causal, window=window, bq=bq, bk=bk)
         # contract over the q rows: p^T dO and ds^T q, no explicit transpose
+        prec = mxu.precision(q_ref.dtype)
         dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            p, do, (((0,), (0,)), ((), ())), precision=prec,
             preferred_element_type=jnp.float32)
         dk_acc[...] += jax.lax.dot_general(
             ds, q_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=prec, preferred_element_type=jnp.float32)
 
     @pl.when(jnp.logical_and(g == group - 1, iq == n_q - 1))
     def _finalize():
@@ -355,7 +361,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, q_offset=0, *,
         out_specs=pl.BlockSpec((1, bq, hd), lambda bh, iq, ik: (bh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -402,7 +408,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, q_offset=0, *,
             pltpu.VMEM((bk, hd), jnp.float32),    # dk accumulator
             pltpu.VMEM((bk, hd), jnp.float32),    # dv accumulator
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary"),
         ),

@@ -29,15 +29,13 @@ import numpy as np
 from repro.kernels.flash_attention import ref
 from repro.kernels.flash_attention.kernel import (flash_attention_bwd_pallas,
                                                   flash_attention_pallas)
+from repro.kernels.spmd import per_batch_shard
 
 
 def _use_pallas() -> bool:
     if os.environ.get("REPRO_PALLAS_INTERPRET") == "1":
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
@@ -108,5 +106,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                                          q_offset=q_offset)
         return ref.attention(q, k, v, causal=causal, window=window,
                              scale=s, q_offset=q_offset)
-    return _make_flash(bool(causal), int(window), s)(
-        q, k, v, jnp.asarray(q_offset, jnp.int32))
+    return per_batch_shard(_make_flash(bool(causal), int(window), s),
+                           q, k, v, jnp.asarray(q_offset, jnp.int32),
+                           split=(True, True, True, False))

@@ -54,7 +54,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import compiler_params
+from repro.kernels import mxu
 
 
 DEFAULT_BM = 256
@@ -76,17 +76,20 @@ def _fwd_kernel(x_ref, w_ref, a_ref, b_ref, scale_ref, y_ref, xa_out_ref,
         xa_ref[...] = jnp.zeros_like(xa_ref)
 
     x = x_ref[...]
-    acc_ref[...] += jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
+    prec = mxu.precision(x_ref.dtype)
+    acc_ref[...] += jnp.dot(x, w_ref[...], precision=prec,
+                            preferred_element_type=jnp.float32)
 
     @pl.when(j == 0)
     def _accum_xa():
-        xa_ref[...] += jnp.dot(x, a_ref[...],
+        xa_ref[...] += jnp.dot(x, a_ref[...], precision=prec,
                                preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         scale = scale_ref[0].astype(jnp.float32)
         delta = jnp.dot(xa_ref[...], b_ref[...].astype(jnp.float32),
+                        precision=mxu.precision(xa_ref.dtype),
                         preferred_element_type=jnp.float32)
         y_ref[...] = (acc_ref[...] + scale * delta).astype(y_ref.dtype)
 
@@ -138,7 +141,7 @@ def lora_matmul_pallas(x, w, a, b, scale, *, bm: int = DEFAULT_BM,
             pltpu.VMEM((bm, bn), jnp.float32),   # acc
             pltpu.VMEM((bm, r), jnp.float32),    # xa
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -153,7 +156,8 @@ def _indexed_kernel(ids_ref, scale_ref, x_ref, w_ref, a_ref, b_ref, y_ref,
                     acc_ref, xa_ref, *, n_k: int):
     """One grid row per request slot: the adapter tiles for this row were
     DMA'd by the scalar-prefetch index maps (a/b block index = ids[row]),
-    so the body is exactly the fused forward at bm=1."""
+    so the body is exactly the fused forward at bm=1.  x/y blocks are
+    (1, 1, b*) over (M, 1, *) arrays; [0] drops the unit row axis."""
     i = pl.program_id(0)
     j = pl.program_id(1)
     k = pl.program_id(2)
@@ -166,34 +170,39 @@ def _indexed_kernel(ids_ref, scale_ref, x_ref, w_ref, a_ref, b_ref, y_ref,
     def _zero_xa():
         xa_ref[...] = jnp.zeros_like(xa_ref)
 
-    x = x_ref[...]
-    acc_ref[...] += jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
+    x = x_ref[0]
+    prec = mxu.precision(x_ref.dtype)
+    acc_ref[...] += jnp.dot(x, w_ref[...], precision=prec,
+                            preferred_element_type=jnp.float32)
 
     @pl.when(j == 0)
     def _accum_xa():
-        xa_ref[...] += jnp.dot(x, a_ref[0],
+        xa_ref[...] += jnp.dot(x, a_ref[0], precision=prec,
                                preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         scale = scale_ref[ids_ref[i]].astype(jnp.float32)
         delta = jnp.dot(xa_ref[...], b_ref[0].astype(jnp.float32),
+                        precision=mxu.precision(xa_ref.dtype),
                         preferred_element_type=jnp.float32)
-        y_ref[...] = (acc_ref[...] + scale * delta).astype(y_ref.dtype)
+        y_ref[0] = (acc_ref[...] + scale * delta).astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
 def lora_matmul_indexed_pallas(x, w, a_pool, b_pool, scale, ids, *,
                                bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
                                interpret: bool = False):
-    """x: (M, K); w: (K, N); a_pool: (P, K, r); b_pool: (P, r, N);
-    scale: (P,); ids: (M,) int32 -> y (M, N).
+    """x: (M, 1, K); w: (K, N); a_pool: (P, K, r); b_pool: (P, r, N);
+    scale: (P,); ids: (M,) int32 -> y (M, 1, N).
 
     S-LoRA-style decode projection: every x row is one serving slot's
     token and gathers its own adapter out of the stacked pool via the
     scalar-prefetched ids in the a/b BlockSpec index maps — the pool
-    stays in HBM, only the referenced (bk, r)/(r, bn) tiles move."""
-    m, k_dim = x.shape
+    stays in HBM, only the referenced (bk, r)/(r, bn) tiles move.  The
+    unit middle axis lets the one-row x/y blocks span the arrays' last
+    two dims: the chip's compiler refuses a (1, bk) block over (M, K)."""
+    m, _, k_dim = x.shape
     _, n = w.shape
     r = a_pool.shape[2]
 
@@ -209,14 +218,16 @@ def lora_matmul_indexed_pallas(x, w, a_pool, b_pool, scale, ids, *,
         num_scalar_prefetch=2,          # ids, scale
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bk), lambda i, j, k, ids, s: (i, k)),    # x
+            pl.BlockSpec((1, 1, bk),
+                         lambda i, j, k, ids, s: (i, 0, k)),          # x
             pl.BlockSpec((bk, bn), lambda i, j, k, ids, s: (k, j)),   # w
             pl.BlockSpec((1, bk, r),
                          lambda i, j, k, ids, s: (ids[i], k, 0)),     # A[ids]
             pl.BlockSpec((1, r, bn),
                          lambda i, j, k, ids, s: (ids[i], 0, j)),     # B[ids]
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j, k, ids, s: (i, j)),
+        out_specs=pl.BlockSpec((1, 1, bn),
+                               lambda i, j, k, ids, s: (i, 0, j)),
         scratch_shapes=[
             pltpu.VMEM((1, bn), jnp.float32),    # acc
             pltpu.VMEM((1, r), jnp.float32),     # xa
@@ -225,8 +236,8 @@ def lora_matmul_indexed_pallas(x, w, a_pool, b_pool, scale, ids, *,
     return pl.pallas_call(
         functools.partial(_indexed_kernel, n_k=n_k),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        compiler_params=compiler_params(
+        out_shape=jax.ShapeDtypeStruct((m, 1, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -251,15 +262,16 @@ def _bwd_dx_kernel(g_ref, w_ref, a_ref, b_ref, scale_ref, dx_ref, gb_ref,
         gb_acc[...] = jnp.zeros_like(gb_acc)
 
     g = g_ref[...]
+    prec = mxu.precision(g_ref.dtype)
     # dx accumulation: g[i, n] @ W[k, n]^T, contracting the n axis
     acc_ref[...] += jax.lax.dot_general(
-        g, w_ref[...], (((1,), (1,)), ((), ())),
+        g, w_ref[...], (((1,), (1,)), ((), ())), precision=prec,
         preferred_element_type=jnp.float32)
 
     @pl.when(k == 0)
     def _accum_gb():
         gb_acc[...] += jax.lax.dot_general(
-            g, b_ref[...], (((1,), (1,)), ((), ())),
+            g, b_ref[...], (((1,), (1,)), ((), ())), precision=prec,
             preferred_element_type=jnp.float32)
 
     @pl.when(n == n_n - 1)
@@ -267,7 +279,8 @@ def _bwd_dx_kernel(g_ref, w_ref, a_ref, b_ref, scale_ref, dx_ref, gb_ref,
         scale = scale_ref[0].astype(jnp.float32)
         low = jax.lax.dot_general(
             gb_acc[...], a_ref[...].astype(jnp.float32),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            (((1,), (1,)), ((), ())), precision=mxu.precision(gb_acc.dtype),
+            preferred_element_type=jnp.float32)
         dx_ref[...] = (acc_ref[...] + scale * low).astype(dx_ref.dtype)
 
     @pl.when(jnp.logical_and(k == 0, n == n_n - 1))
@@ -286,11 +299,14 @@ def _bwd_dab_kernel(x_ref, g_ref, xa_ref, gb_ref, scale_ref, da_ref, db_ref):
     scale = scale_ref[0].astype(jnp.float32)
     # dA += s x[i]^T @ gb[i]; dB += s xa[i]^T @ g[i] — the (K, r) / (r, N)
     # output windows never change block, so accumulating into them is safe.
+    # gb and xa are f32: both dots take full precision.
     da_ref[...] += scale * jax.lax.dot_general(
-        x_ref[...], gb_ref[...], (((0,), (0,)), ((), ())),
+        x_ref[...].astype(jnp.float32), gb_ref[...], (((0,), (0,)), ((), ())),
+        precision=mxu.precision(gb_ref.dtype),
         preferred_element_type=jnp.float32)
     db_ref[...] += scale * jax.lax.dot_general(
-        xa_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
+        xa_ref[...], g_ref[...].astype(jnp.float32), (((0,), (0,)), ((), ())),
+        precision=mxu.precision(xa_ref.dtype),
         preferred_element_type=jnp.float32)
 
 
@@ -340,7 +356,7 @@ def lora_matmul_bwd_pallas(x, w, a, b, scale, g, xa, *,
             pltpu.VMEM((bm, bk), jnp.float32),   # dx accumulator
             pltpu.VMEM((bm, r), jnp.float32),    # gb accumulator
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -364,7 +380,7 @@ def lora_matmul_bwd_pallas(x, w, a, b, scale, g, xa, *,
             jax.ShapeDtypeStruct((k_dim, r), jnp.float32),
             jax.ShapeDtypeStruct((r, n), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -32,15 +32,13 @@ from repro.kernels.lora_matmul import ref
 from repro.kernels.lora_matmul.kernel import (lora_matmul_bwd_pallas,
                                               lora_matmul_indexed_pallas,
                                               lora_matmul_pallas)
+from repro.kernels.spmd import per_batch_shard
 
 
 def _use_pallas() -> bool:
     if os.environ.get("REPRO_PALLAS_INTERPRET") == "1":
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
@@ -91,6 +89,25 @@ def _pallas_path(x, w, a, b, scale):
     y, xa = lora_matmul_pallas(x2, w, a_p, b_p, scale, bm=bm, bn=bn, bk=bk,
                                interpret=_interpret())
     return y[:m0].reshape(*lead, n), xa[:m0]
+
+
+def _sharded_fwd(x, w, a, b, scale):
+    """_pallas_path per batch shard of x (kernels.spmd)."""
+    return per_batch_shard(_pallas_path, x, w, a, b, scale,
+                           split=(True, False, False, False, False))
+
+
+def _sharded_bwd(x, w, a, b, scale, g, xa):
+    """_pallas_bwd_path per batch shard: dA/dB/dscale are sums over the
+    rows, so each shard returns its partial and they are added here."""
+    def partials(*t):
+        dx, da, db, dscale = _pallas_bwd_path(*t)
+        return dx, da[None], db[None], dscale[None]
+
+    dx, da, db, dscale = per_batch_shard(
+        partials, x, w, a, b, scale, g, xa,
+        split=(True, False, False, False, False, True, True))
+    return dx, da.sum(0), db.sum(0), dscale.sum(0)
 
 
 def _pallas_bwd_path(x, w, a, b, scale, g, xa):
@@ -150,12 +167,12 @@ def _make_lora(lora_only: bool):
     @jax.custom_vjp
     def f(x, w, a, b, scale):
         if _use_pallas():
-            return _pallas_path(x, w, a, b, scale)[0]
+            return _sharded_fwd(x, w, a, b, scale)[0]
         return ref.lora_matmul(x, w, a, b, scale)
 
     def fwd(x, w, a, b, scale):
         if _use_pallas():
-            y, xa = _pallas_path(x, w, a, b, scale)
+            y, xa = _sharded_fwd(x, w, a, b, scale)
         else:
             y = ref.lora_matmul(x, w, a, b, scale)
             xa = None
@@ -164,7 +181,7 @@ def _make_lora(lora_only: bool):
     def bwd(res, g):
         x, w, a, b, scale, xa = res
         if xa is not None and _use_pallas():
-            dx, da, db, dscale = _pallas_bwd_path(x, w, a, b, scale, g, xa)
+            dx, da, db, dscale = _sharded_bwd(x, w, a, b, scale, g, xa)
             if lora_only:
                 # symbolic zero: never computed, DCE'd when unused
                 dw = jnp.zeros_like(w)
@@ -199,7 +216,7 @@ def lora_matmul_indexed(x, w, a_pool, b_pool, scale, ids):
     _, bn, bk = _blocks_for(x2.shape[0], n, k_dim)
     a_p, _ = _pad_to(a_pool, 8, 2)
     b_p, _ = _pad_to(b_pool, 8, 1)
-    y = lora_matmul_indexed_pallas(x2, w, a_p, b_p, scale, row_ids,
+    y = lora_matmul_indexed_pallas(x2[:, None], w, a_p, b_p, scale, row_ids,
                                    bn=bn, bk=bk, interpret=_interpret())
     return y.reshape(lead + (n,))
 

@@ -21,7 +21,9 @@ materializes it between the two XLA kernels).
 
 Alignment: callers pad M to the block multiple and d to the 128-lane
 multiple (zero padding is amax-neutral).  Padded channels quantize against
-scale EPS/127 and dequantize to exact zero.
+scale EPS/127 and dequantize to exact zero.  The per-message scale travels
+as (G, 1, d), so its (1, 1, d) block spans the array's last two dims: the
+chip's compiler refuses a (1, d) block over a (G, d) array once G > 1.
 """
 
 from __future__ import annotations
@@ -33,7 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import compiler_params
 
 DEFAULT_BM = 256
 EPS = 1e-12
@@ -63,7 +64,7 @@ def _quant_body(x_ref, amax_ref, *, emit):
 def _quantize_kernel(x_ref, q_ref, scale_ref, amax_ref):
     def emit(x, scale):
         q_ref[0] = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
-        scale_ref[...] = scale
+        scale_ref[0] = scale
 
     _quant_body(x_ref, amax_ref, emit=emit)
 
@@ -77,7 +78,7 @@ def _roundtrip_kernel(x_ref, y_ref, amax_ref):
 
 
 def _dequantize_kernel(q_ref, scale_ref, x_ref):
-    x_ref[0] = (q_ref[0].astype(jnp.float32) * scale_ref[...]) \
+    x_ref[0] = (q_ref[0].astype(jnp.float32) * scale_ref[0]) \
         .astype(x_ref.dtype)
 
 
@@ -94,7 +95,7 @@ def _two_phase_call(kernel, x, out_shapes, out_specs, *, bm, interpret):
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],   # amax
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -103,14 +104,14 @@ def _two_phase_call(kernel, x, out_shapes, out_specs, *, bm, interpret):
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
 def quantize_pallas(x, *, bm: int = DEFAULT_BM, interpret: bool = False):
-    """x (G, M, d) -> (q (G, M, d) int8, scale (G, d) f32)."""
+    """x (G, M, d) -> (q (G, M, d) int8, scale (G, 1, d) f32)."""
     g, m, d = x.shape
     return _two_phase_call(
         _quantize_kernel, x,
         out_shapes=(jax.ShapeDtypeStruct((g, m, d), jnp.int8),
-                    jax.ShapeDtypeStruct((g, d), jnp.float32)),
+                    jax.ShapeDtypeStruct((g, 1, d), jnp.float32)),
         out_specs=(pl.BlockSpec((1, bm, d), lambda gi, p, i: (gi, i, 0)),
-                   pl.BlockSpec((1, d), lambda gi, p, i: (gi, 0))),
+                   pl.BlockSpec((1, 1, d), lambda gi, p, i: (gi, 0, 0))),
         bm=bm, interpret=interpret)
 
 
@@ -128,7 +129,7 @@ def roundtrip_pallas(x, *, bm: int = DEFAULT_BM, interpret: bool = False):
 @functools.partial(jax.jit, static_argnames=("bm", "interpret", "dtype"))
 def dequantize_pallas(q, scale, *, dtype=jnp.float32, bm: int = DEFAULT_BM,
                       interpret: bool = False):
-    """(q (G, M, d) int8, scale (G, d) f32) -> x_hat (G, M, d) `dtype`."""
+    """(q (G, M, d) int8, scale (G, 1, d) f32) -> x_hat (G, M, d) `dtype`."""
     g, m, d = q.shape
     if m % bm:
         raise ValueError(f"rows {m} not divisible by block {bm}; "
@@ -137,10 +138,10 @@ def dequantize_pallas(q, scale, *, dtype=jnp.float32, bm: int = DEFAULT_BM,
         _dequantize_kernel,
         grid=(g, m // bm),
         in_specs=[pl.BlockSpec((1, bm, d), lambda gi, i: (gi, i, 0)),
-                  pl.BlockSpec((1, d), lambda gi, i: (gi, 0))],
+                  pl.BlockSpec((1, 1, d), lambda gi, i: (gi, 0, 0))],
         out_specs=pl.BlockSpec((1, bm, d), lambda gi, i: (gi, i, 0)),
         out_shape=jax.ShapeDtypeStruct((g, m, d), dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -26,15 +26,13 @@ from repro.kernels.smashed_quant import ref
 from repro.kernels.smashed_quant.kernel import (DEFAULT_BM, dequantize_pallas,
                                                 quantize_pallas,
                                                 roundtrip_pallas)
+from repro.kernels.spmd import per_batch_shard
 
 
 def _use_pallas() -> bool:
     if os.environ.get("REPRO_PALLAS_INTERPRET") == "1":
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
@@ -68,13 +66,33 @@ def _pad(x3):
     return x3, bm, m, d
 
 
+def _quantize_path(x3):
+    xp, bm, m, d = _pad(x3)
+    q, scale = quantize_pallas(xp, bm=bm, interpret=_interpret())
+    return q[:, :m, :d], scale[:, 0, :d]
+
+
+def _dequantize_path(q3, scale3, dtype):
+    g, m, d = q3.shape
+    bm = _block_rows(m)
+    pm, pd = (-m) % bm, (-d) % 128
+    if pm or pd:
+        q3 = jnp.pad(q3, ((0, 0), (0, pm), (0, pd)))
+        scale3 = jnp.pad(scale3, ((0, 0), (0, pd)))
+    return dequantize_pallas(q3, scale3[:, None], dtype=dtype, bm=bm,
+                             interpret=_interpret())[:, :m, :d]
+
+
+def _roundtrip_path(x3):
+    xp, bm, m, d = _pad(x3)
+    return roundtrip_pallas(xp, bm=bm, interpret=_interpret())[:, :m, :d]
+
+
 def int8_quantize_smashed(x):
     """x (..., d) -> (q int8 same shape, scale (G, d) | (d,))."""
     x3, shape = _canon(x)
     if _use_pallas():
-        xp, bm, m, d = _pad(x3)
-        q, scale = quantize_pallas(xp, bm=bm, interpret=_interpret())
-        q, scale = q[:, :m, :d], scale[:, :d]
+        q, scale = per_batch_shard(_quantize_path, x3, split=(True,))
     else:
         q, scale = ref.quantize(x3)
     q = q.reshape(shape)
@@ -86,14 +104,8 @@ def int8_dequantize_smashed(q, scale, dtype=jnp.float32):
     q3, shape = _canon(q)
     scale3 = scale[None] if len(shape) == 2 else scale
     if _use_pallas():
-        g, m, d = q3.shape
-        bm = _block_rows(m)
-        pm, pd = (-m) % bm, (-d) % 128
-        if pm or pd:
-            q3 = jnp.pad(q3, ((0, 0), (0, pm), (0, pd)))
-            scale3 = jnp.pad(scale3, ((0, 0), (0, pd)))
-        x = dequantize_pallas(q3, scale3, dtype=dtype, bm=bm,
-                              interpret=_interpret())[:, :m, :d]
+        x = per_batch_shard(lambda q_, s_: _dequantize_path(q_, s_, dtype),
+                            q3, scale3, split=(True, True))
     else:
         x = ref.dequantize(q3, scale3, dtype)
     return x.reshape(shape)
@@ -103,8 +115,7 @@ def int8_roundtrip_smashed(x):
     """Fused wire round trip dequant(quant(x)), same shape/dtype as x."""
     x3, shape = _canon(x)
     if _use_pallas():
-        xp, bm, m, d = _pad(x3)
-        y = roundtrip_pallas(xp, bm=bm, interpret=_interpret())[:, :m, :d]
+        y = per_batch_shard(_roundtrip_path, x3, split=(True,))
     else:
         y = ref.roundtrip(x3)
     return y.reshape(shape)

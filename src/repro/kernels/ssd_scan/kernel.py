@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import compiler_params
-
 
 def _kernel(a_head_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_ref,
             *, q: int, n_chunks: int):
@@ -116,7 +114,7 @@ def ssd_scan_pallas(x, dt, a, bm, c, *, chunk: int = 256,
         out_specs=pl.BlockSpec((1, 1, chunk, p), bh_index),
         out_shape=jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -20,10 +20,7 @@ from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
 def _use_pallas() -> bool:
     if os.environ.get("REPRO_PALLAS_INTERPRET") == "1":
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @functools.lru_cache(maxsize=None)

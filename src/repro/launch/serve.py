@@ -4,12 +4,12 @@
       --reduced --adapters 4 --requests 16 --arrival-rate 8 \
       --num-slots 4 --page-size 16
 
-Thin CLI over runtime.serving.ServingEngine: builds (or loads) a stacked
-per-client adapter pool, synthesizes a Poisson request workload, runs the
-engine, and prints latency/throughput.  With --ckpt the pool is the
-SplitFT checkpoint's per-client personalized adapters — gathered from
-PopulationStore slots in population mode, so --adapters picks how many
-fleet members to serve.
+Thin CLI over runtime.serving.ServingEngine: `build(args)` builds (or
+loads) a stacked per-client adapter pool and synthesizes a Poisson
+request workload; `main` runs the engine and prints latency/throughput.
+With --ckpt the pool is the SplitFT checkpoint's per-client personalized
+adapters — gathered from PopulationStore slots in population mode, so
+--adapters picks how many fleet members to serve.
 """
 
 from __future__ import annotations
@@ -44,9 +44,8 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main(argv=None):
-    args = build_parser().parse_args(argv)
-
+def build(args):
+    """(ServingEngine, [Request]) for parsed CLI args."""
     import jax
     from repro.config import reduced as reduced_cfg
     from repro.configs import get_config
@@ -101,7 +100,15 @@ def main(argv=None):
         tokens=rng.integers(3, v, size=args.prompt_len),
         max_new=args.gen, arrival=float(arrivals[i]))
         for i in range(args.requests)]
+    return engine, reqs
 
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
+    engine, reqs = build(args)
     t0 = time.time()
     results = engine.run(reqs)
     wall = time.time() - t0

@@ -3,9 +3,10 @@
   PYTHONPATH=src python -m repro.launch.train --arch gpt2-small \
       --rounds 300 --partition dirichlet --alpha 0.9 --adaptive
 
-Runs the paper's workflow on whatever devices are available (CPU for the
-paper-scale models; a TPU mesh transparently via --mesh).  Artifacts:
-history JSONL + checkpoints under --out.
+Runs the paper's workflow on the default JAX device: the Pallas kernels
+on a TPU, their jnp oracles on CPU.  `build_system(args)` returns the
+SplitFTSystem that `main` drives (chip_smoke.py reaches it there).
+Artifacts: history JSONL + checkpoints under --out.
 
 The adaptive co-controller (docs/ARCHITECTURE.md) is reached with
   --controller co --rank-buckets 2,4,8 \
@@ -39,6 +40,8 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--arch", default="gpt2-small")
     ap.add_argument("--rounds", type=int, default=100)
     ap.add_argument("--clients", type=int, default=0)
+    ap.add_argument("--batch-size", type=int, default=0,
+                    help="per-client batch; default: the arch config's")
     ap.add_argument("--partition", default=None, choices=[None, "iid",
                                                           "dirichlet"])
     ap.add_argument("--alpha", type=float, default=None)
@@ -172,12 +175,16 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main(argv=None):
-    args = build_parser().parse_args(argv)
-
+def build_system(args, *, policy=None):
+    """The SplitFTSystem for parsed CLI args (checkpoints under
+    <args.out>/ckpt; nothing restored or run yet).  policy: a
+    models.common.ShardingPolicy for the client-axis-sharded engine."""
     from repro.config import reduced as reduced_cfg
     from repro.configs import get_config
     from repro.core.system import SplitFTSystem, SystemConfig
+    from repro.models.common import NO_SHARDING
+
+    policy = policy or NO_SHARDING
 
     arch = get_config(args.arch)
     if args.reduced:
@@ -199,6 +206,9 @@ def main(argv=None):
             arch.lora,
             r_cut=args.r_cut or arch.lora.r_cut,
             r_others=args.r_others or arch.lora.r_others))
+    if args.batch_size:
+        arch = arch.replace(train=dataclasses.replace(
+            arch.train, batch_size=args.batch_size))
     if args.lr:
         arch = arch.replace(train=dataclasses.replace(
             arch.train, lr_client=args.lr, lr_server=args.lr))
@@ -236,7 +246,15 @@ def main(argv=None):
         edge_groups=args.edge_groups,
         checkpoint_dir=os.path.join(args.out, "ckpt"),
         checkpoint_every=max(args.rounds // 5, 1))
-    system = SplitFTSystem(arch, sys_cfg, seed=args.seed)
+    return SplitFTSystem(arch, sys_cfg, policy=policy, seed=args.seed)
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
+    system = build_system(args)
     if system.restore():
         print(f"resumed from round {int(system.state['round'])}")
 

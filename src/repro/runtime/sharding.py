@@ -18,6 +18,7 @@ here touches real device memory, which is what the dry-run requires.
 
 from __future__ import annotations
 
+import functools
 import math
 import re
 from typing import Any, Dict, Optional, Tuple
@@ -91,6 +92,20 @@ def state_specs(state, mesh: Mesh):
                             for i in range(nd))
         specs.append(fit_spec(np.shape(leaf), logical, mesh))
     return jax.tree.unflatten(treedef, specs)
+
+
+def under_mesh(fn, mesh: Optional[Mesh]):
+    """fn, traced with `mesh` as the context mesh (fn itself without a
+    mesh): Pallas calls read it to run per shard (kernels.spmd)."""
+    if mesh is None:
+        return fn
+
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args, **kwargs)
+
+    return traced
 
 
 def constrain_state(state, mesh: Optional[Mesh]):

@@ -63,12 +63,18 @@ def test_dotted_module_references_resolve():
 
 def test_cli_flags_exist():
     """Every `--flag` the docs mention must be a real option of
-    repro.launch.train's or repro.launch.serve's parser (or
-    benchmarks.run's --dry-run)."""
+    repro.launch.train's, repro.launch.serve's or chip_smoke.py's parser
+    (or benchmarks.run's --dry-run)."""
+    import importlib.util
+
     from repro.launch.serve import build_parser as serve_parser
     from repro.launch.train import build_parser as train_parser
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", REPO / "chip_smoke.py")
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
     known = {"--dry-run"}
-    for parser in (train_parser(), serve_parser()):
+    for parser in (train_parser(), serve_parser(), chip_smoke._parser()):
         for act in parser._actions:
             known.update(act.option_strings)
     flags = set(re.findall(r"(?<![\w-])--[a-z][a-z0-9-]*", _text()))

@@ -46,7 +46,7 @@ def test_int8_kernels_match_ref(shape):
     q_ref, scale_ref = sq_ref.quantize(x)
     np.testing.assert_array_equal(np.asarray(q[:, :m, :d]),
                                   np.asarray(q_ref))
-    np.testing.assert_allclose(np.asarray(scale[:, :d]),
+    np.testing.assert_allclose(np.asarray(scale[:, 0, :d]),
                                np.asarray(scale_ref), rtol=1e-6)
     deq = dequantize_pallas(q, scale, bm=bm, interpret=True)[:, :m, :d]
     np.testing.assert_allclose(np.asarray(deq),
