@@ -1,0 +1,13 @@
+"""Device time of the jitted round step per round: the runs of its XLA
+module inside the window (the largest `jit_step` program, the round
+engine; the C3 evaluation step is the smaller one), over the rounds."""
+
+from chipbench.programs import split_step_modules
+
+
+def read(ctx):
+    train, _ = split_step_modules(ctx["trace"])
+    rounds = ctx["counters"].get("rounds")
+    if train is None or not rounds:
+        return None
+    return 1e3 * train / rounds
