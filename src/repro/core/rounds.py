@@ -140,9 +140,10 @@ def make_train_step(model: Model, *, policy: ShardingPolicy = NO_SHARDING,
                     num_edges: int = 1,
                     server_step_norm: bool = True,
                     jit: bool = True):
-    """Build the jitted round step.
+    """Build the jitted round step (`round_step`, so its compiled module
+    is `jit_round_step`).
 
-    step(base_params, state, batch, weights, active, lr_c, lr_s)
+    round_step(base_params, state, batch, weights, active, lr_c, lr_s)
       -> (state', metrics)
 
     weights: (N,) combined FedAvg x C3 weights (w_i * |D_i|/|D|);
@@ -254,7 +255,7 @@ def make_train_step(model: Model, *, policy: ShardingPolicy = NO_SHARDING,
 
     mesh = policy.mesh
 
-    def step(base_params, state, batch, weights, active, lr_c, lr_s):
+    def round_step(base_params, state, batch, weights, active, lr_c, lr_s):
         state = constrain_state(state, mesh)
         batch = constrain_client_batch(batch, mesh)
         cad, sad = state["client_adapters"], state["server_adapters"]
@@ -348,10 +349,10 @@ def make_train_step(model: Model, *, policy: ShardingPolicy = NO_SHARDING,
         metrics["total"] = total
         return constrain_state(new_state, mesh), metrics
 
-    step = under_mesh(step, mesh)
+    round_step = under_mesh(round_step, mesh)
     if jit:
-        return jax.jit(step, donate_argnums=(1,))
-    return step
+        return jax.jit(round_step, donate_argnums=(1,))
+    return round_step
 
 
 def _round_aggregate(model: Model, *, compress, topk_frac, agg_every,
@@ -443,7 +444,7 @@ def _make_local_steps_step(model: Model, opt, smasher, *, policy, remat,
     K = max_local_steps
     mesh = policy.mesh
 
-    def step(base_params, state, batch, weights, active, lr_c, lr_s):
+    def round_step(base_params, state, batch, weights, active, lr_c, lr_s):
         state = constrain_state(state, mesh)
         batch = constrain_client_batch(batch, mesh, step_axis=True)
         cad, sad = state["client_adapters"], state["server_adapters"]
@@ -539,10 +540,10 @@ def _make_local_steps_step(model: Model, opt, smasher, *, policy, remat,
             new_state["smashed_ef"] = new_sm_ef
         return constrain_state(new_state, mesh), metrics
 
-    step = under_mesh(step, mesh)
+    round_step = under_mesh(round_step, mesh)
     if jit:
-        return jax.jit(step, donate_argnums=(1,))
-    return step
+        return jax.jit(round_step, donate_argnums=(1,))
+    return round_step
 
 
 # ---------------------------------------------------------------------------
@@ -580,7 +581,7 @@ def _make_async_step(model: Model, opt, smasher, *, policy, remat,
     M = buffer_size
     mesh = policy.mesh
 
-    def step(base_params, state, batch, weights, active, lr_c, lr_s):
+    def round_step(base_params, state, batch, weights, active, lr_c, lr_s):
         state = constrain_state(state, mesh)
         batch = constrain_client_batch(batch, mesh)
         cad, sad = state["client_adapters"], state["server_adapters"]
@@ -686,19 +687,20 @@ def _make_async_step(model: Model, opt, smasher, *, policy, remat,
         metrics["aggregated"] = aggregate
         return constrain_state(new_state, mesh), metrics
 
-    step = under_mesh(step, mesh)
+    round_step = under_mesh(round_step, mesh)
     if jit:
-        return jax.jit(step, donate_argnums=(1,))
-    return step
+        return jax.jit(round_step, donate_argnums=(1,))
+    return round_step
 
 
 def make_eval_step(model: Model, *, policy: ShardingPolicy = NO_SHARDING,
                    ce_chunk: int = 0, jit: bool = True):
     """Evaluate the GLOBAL model (paper b4) on per-client eval batches.
 
-    Returns per-client (loss, accuracy) — the inputs to the C3 rule."""
+    Returns per-client (loss, accuracy) — the inputs to the C3 rule.
+    The function is `c3_eval_step`, so its module is `jit_c3_eval_step`."""
 
-    def step(base_params, state, batch, weights):
+    def c3_eval_step(base_params, state, batch, weights):
         eff = split.serve_adapters(model, state["client_adapters"],
                                    state["server_adapters"], state["cuts"],
                                    weights,
@@ -708,8 +710,8 @@ def make_eval_step(model: Model, *, policy: ShardingPolicy = NO_SHARDING,
                                        per_client=True)
         return per_loss, metrics
 
-    step = under_mesh(step, policy.mesh)
-    return jax.jit(step) if jit else step
+    c3_eval_step = under_mesh(c3_eval_step, policy.mesh)
+    return jax.jit(c3_eval_step) if jit else c3_eval_step
 
 
 def with_error_feedback(state: Params) -> Params:

@@ -74,6 +74,7 @@ from repro.models.common import NO_SHARDING
 from repro.models.model import Model, build_model
 from repro.runtime import straggler
 from repro.runtime import timemodel
+from repro.runtime.spans import span
 from repro.runtime import traces as traces_lib
 from repro.runtime.elastic import ClientPool
 from repro.runtime.population import CohortSampler, PopulationStore
@@ -204,44 +205,52 @@ class SplitFTSystem:
                 "the cohort")
 
         # ---- data (C4) ----
-        tok = HashTokenizer(arch.model.vocab_size)
-        texts = synthetic_corpus(self.sys.num_samples, seed=arch.data.seed)
-        self.samples = [np.asarray(tok.encode(t), np.int32) for t in texts]
-        lengths = [len(s) for s in self.samples]
+        with span("splitft.setup.corpus"):
+            tok = HashTokenizer(arch.model.vocab_size)
+            texts = synthetic_corpus(self.sys.num_samples,
+                                     seed=arch.data.seed)
+            self.samples = [np.asarray(tok.encode(t), np.int32)
+                            for t in texts]
+            lengths = [len(s) for s in self.samples]
         # fleet mode partitions over the N clients directly; population
         # mode partitions over a fixed shard pool and maps pid -> shard
         # (pid % shards), so the partition cost is O(shards), not O(P),
         # and pid p sees the same shard at any population size >= shards
         self._n_shards = (n if not self.population
                           else min(self.population, max(n, 256)))
-        parts = partition_dataset(
-            lengths, self._n_shards, strategy=arch.data.partition,
-            alpha=arch.data.alpha, num_classes=arch.data.num_length_classes,
-            seed=arch.data.seed)
+        with span("splitft.setup.partition"):
+            parts = partition_dataset(
+                lengths, self._n_shards, strategy=arch.data.partition,
+                alpha=arch.data.alpha,
+                num_classes=arch.data.num_length_classes,
+                seed=arch.data.seed)
         self.parts = parts
-        eval_texts = synthetic_corpus(self.sys.eval_samples,
-                                      seed=arch.data.seed + 777)
-        eval_tokens = [np.asarray(tok.encode(t), np.int32)
-                       for t in eval_texts]
+        with span("splitft.setup.corpus"):
+            eval_texts = synthetic_corpus(self.sys.eval_samples,
+                                          seed=arch.data.seed + 777)
+            eval_tokens = [np.asarray(tok.encode(t), np.int32)
+                           for t in eval_texts]
         self._eval_tokens = eval_tokens
-        if not self.population:
-            self.loaders = make_client_loaders(
-                self.samples, parts, batch_size=arch.train.batch_size,
-                seq_len=arch.train.seq_len, seed=seed)
-            self.eval_loaders = make_client_loaders(
-                [t for t in eval_tokens], [np.arange(len(eval_tokens))] * n,
-                batch_size=arch.train.batch_size,
-                seq_len=arch.train.seq_len, seed=seed + 999)
-        else:
-            # loaders are built per-pid on cohort install; seed the slots
-            # with pids 0..n-1 (exactly the first P == C cohort, which
-            # the sampler returns without consuming RNG)
-            self._loader_cache: Dict[int, ClientDataLoader] = {}
-            self._eval_loader_cache: Dict[int, ClientDataLoader] = {}
-            pids0 = np.arange(n, dtype=np.int64)
-            self.loaders = [self._loader_for(int(p)) for p in pids0]
-            self.eval_loaders = [self._eval_loader_for(int(p))
-                                 for p in pids0]
+        with span("splitft.setup.loaders"):
+            if not self.population:
+                self.loaders = make_client_loaders(
+                    self.samples, parts, batch_size=arch.train.batch_size,
+                    seq_len=arch.train.seq_len, seed=seed)
+                self.eval_loaders = make_client_loaders(
+                    [t for t in eval_tokens],
+                    [np.arange(len(eval_tokens))] * n,
+                    batch_size=arch.train.batch_size,
+                    seq_len=arch.train.seq_len, seed=seed + 999)
+            else:
+                # loaders are built per-pid on cohort install; seed the
+                # slots with pids 0..n-1 (exactly the first P == C
+                # cohort, which the sampler returns without consuming RNG)
+                self._loader_cache: Dict[int, ClientDataLoader] = {}
+                self._eval_loader_cache: Dict[int, ClientDataLoader] = {}
+                pids0 = np.arange(n, dtype=np.int64)
+                self.loaders = [self._loader_for(int(p)) for p in pids0]
+                self.eval_loaders = [self._eval_loader_for(int(p))
+                                     for p in pids0]
 
         # ---- round scheduler (policy) + straggler simulation ----
         sched_name = self.sys.scheduler
@@ -351,12 +360,14 @@ class SplitFTSystem:
         self.sim_clock = 0.0           # cumulative simulated seconds
 
         # ---- model/state (engine) ----
-        key = jax.random.PRNGKey(seed)
-        k_base, k_state = jax.random.split(key)
-        self.base_params = self.model.init_params(k_base)
-        self.state = rounds.init_state(self.model, k_state, num_clients=n)
-        if self.sys.compress == "topk":
-            self.state = rounds.with_error_feedback(self.state)
+        with span("splitft.setup.init"):
+            key = jax.random.PRNGKey(seed)
+            k_base, k_state = jax.random.split(key)
+            self.base_params = self.model.init_params(k_base)
+            self.state = rounds.init_state(self.model, k_state,
+                                           num_clients=n)
+            if self.sys.compress == "topk":
+                self.state = rounds.with_error_feedback(self.state)
         self.smashed_compress = (arch.split.smashed_compress
                                  if self.sys.smashed_compress is None
                                  else self.sys.smashed_compress)
@@ -372,7 +383,8 @@ class SplitFTSystem:
                 f"(got {self.smashed_compress!r}); int8/fp8 are "
                 "memoryless round-trips with no residual to feed back")
         if use_smashed_ef:
-            self.state = rounds.with_smashed_ef(self.state, self.model)
+            with span("splitft.setup.init"):
+                self.state = rounds.with_smashed_ef(self.state, self.model)
 
         # ---- co-controller search space (cut x rank x compressor) ----
         self.acc_dead_band = (arch.split.acc_dead_band
@@ -435,27 +447,29 @@ class SplitFTSystem:
         init_choice = (self.comp_buckets.index(self.smashed_compress)
                        if self.smashed_compress in self.comp_buckets
                        else 0)
-        self.state = rounds.prepare_state(
-            self.state, max_local_steps=self.scheduler.max_steps,
-            async_buffer=is_async,
-            rank_cut=init_rank if co else None,
-            smashed_choice=init_choice if co else None,
-            topk_frac=(self.smashed_topk_frac
-                       if (co and self.continuous_topk) else None),
-            edge_groups=self.num_edges)
-        self.train_step = rounds.make_train_step(
-            self.model, policy=policy, remat=arch.train.remat,
-            agg_every=self.sys.agg_every, compress=self.sys.compress,
-            topk_frac=self.sys.topk_frac,
-            smashed_compress=self.smashed_compress,
-            smashed_topk_frac=self.smashed_topk_frac,
-            compressor_buckets=self.comp_buckets if co else None,
-            max_local_steps=self.scheduler.max_steps,
-            async_buffer=is_async, buffer_size=buf,
-            staleness_power=spow, num_edges=self.num_edges,
-            server_step_norm=self.server_step_norm, jit=jit)
-        self.eval_step = rounds.make_eval_step(self.model, policy=policy,
-                                               jit=jit)
+        with span("splitft.setup.init"):
+            self.state = rounds.prepare_state(
+                self.state, max_local_steps=self.scheduler.max_steps,
+                async_buffer=is_async,
+                rank_cut=init_rank if co else None,
+                smashed_choice=init_choice if co else None,
+                topk_frac=(self.smashed_topk_frac
+                           if (co and self.continuous_topk) else None),
+                edge_groups=self.num_edges)
+        with span("splitft.setup.engine"):
+            self.train_step = rounds.make_train_step(
+                self.model, policy=policy, remat=arch.train.remat,
+                agg_every=self.sys.agg_every, compress=self.sys.compress,
+                topk_frac=self.sys.topk_frac,
+                smashed_compress=self.smashed_compress,
+                smashed_topk_frac=self.smashed_topk_frac,
+                compressor_buckets=self.comp_buckets if co else None,
+                max_local_steps=self.scheduler.max_steps,
+                async_buffer=is_async, buffer_size=buf,
+                staleness_power=spow, num_edges=self.num_edges,
+                server_step_norm=self.server_step_norm, jit=jit)
+            self.eval_step = rounds.make_eval_step(self.model,
+                                                   policy=policy, jit=jit)
 
         # ---- C3 state ----
         self.c3_weights = np.ones(n)
@@ -853,9 +867,20 @@ class SplitFTSystem:
         allocation — cuts only (paper accuracy rule) or the full (cut,
         rank-at-cut, compressor) triple via the predicted-makespan
         co-controller (adaptive.co_adjust)."""
-        e_loss, e_metrics = self.eval_step(
-            self.base_params, self.state, self._eval_batch(r), weights)
-        accs = np.asarray(e_metrics["accuracy"])
+        with span("splitft.c3.batch"):
+            eval_batch = self._eval_batch(r)
+        with span("splitft.c3.dispatch"):
+            e_loss, e_metrics = self.eval_step(
+                self.base_params, self.state, eval_batch, weights)
+        with span("splitft.wait.c3"):
+            accs = np.asarray(e_metrics["accuracy"])
+        with span("splitft.c3.rule"):
+            self._c3_rule(r, rec, accs, e_metrics, times)
+
+    def _c3_rule(self, r: int, rec: Dict[str, Any], accs: np.ndarray,
+                 e_metrics, times: Optional[np.ndarray]):
+        """C3's host side: aggregation weights from the evaluation, then
+        the controller's new allocation written back to round state."""
         rec["eval_ce"] = np.asarray(e_metrics["ce"])
         rec["eval_accuracy"] = accs
         self.c3_weights = adaptive.update_weights(
@@ -908,14 +933,16 @@ class SplitFTSystem:
         """Round epilogue shared by the barrier and async host loops:
         C3 adjustment, history, callback, checkpoint cadence, logging."""
         if self._adaptive and (r + 1) % self.sys.adjust_every == 0:
-            weights = jnp.asarray(self.combined_weights(), jnp.float32)
-            self._adjust_c3(r, rec, weights, rec.get("round_time_sim"))
+            with span("splitft.c3", round=r):
+                weights = jnp.asarray(self.combined_weights(), jnp.float32)
+                self._adjust_c3(r, rec, weights, rec.get("round_time_sim"))
         self.history.append(rec)
         if callback:
             callback(rec)
         if self.ckpt and self.sys.checkpoint_every and \
                 (r + 1) % self.sys.checkpoint_every == 0:
-            self.save(r + 1)
+            with span("splitft.round.checkpoint", round=r):
+                self.save(r + 1)
         if log_every and (r + 1) % log_every == 0:
             print(f"[round {r + 1}] loss={rec['loss']:.4f} "
                   f"acc={rec['accuracy'].mean():.4f} "
@@ -945,30 +972,47 @@ class SplitFTSystem:
         k = self.scheduler.max_steps
         start = int(self.state["round"])
         for r in range(start, start + num_rounds):
-            self._pop_gather()         # population mode: next cohort in
+            with span("splitft.round", round=r):
+                self._barrier_round(r, k, lr_c, lr_s, log_every, callback)
+        return self.history
+
+    def _barrier_round(self, r: int, k: int, lr_c, lr_s, log_every: int,
+                       callback: Optional[Callable]):
+        """One barrier round, one span per phase (runtime.spans); the
+        host's waits on the device are `splitft.wait.*` spans of their
+        own."""
+        if self.store is not None:
+            with span("splitft.round.gather"):
+                self._pop_gather()     # population mode: next cohort in
+        with span("splitft.round.plan"):
             plan, cb = self._plan_round(r)
-            t0 = self.sim_clock        # the round's launch instant
+        t0 = self.sim_clock            # the round's launch instant
+        with span("splitft.round.batch"):
             batch = (self._train_batch(r) if k == 1
                      else self._train_batches(r, k))
+        with span("splitft.round.dispatch"):
             weights = jnp.asarray(self.combined_weights(), jnp.float32)
             if "step_budgets" in self.state:
                 self.state["step_budgets"] = jnp.asarray(
                     plan.step_budgets, jnp.int32)
             active_j = jnp.asarray(plan.active, jnp.float32)
-
             self.state, metrics = self.train_step(
                 self.base_params, self.state, batch, weights, active_j,
                 lr_c, lr_s)
-            self.sim_clock += plan.sim_time
-            if plan.phases is not None:
-                # telemetry feedback: the plan's charged phase matrix is
-                # exactly what the clock just billed this round
-                self._observe_phases(r, plan.phases, plan.active, cb, t0)
+        self.sim_clock += plan.sim_time
+        if plan.phases is not None:
+            # telemetry feedback: the plan's charged phase matrix is
+            # exactly what the clock just billed this round
+            self._observe_phases(r, plan.phases, plan.active, cb, t0)
 
+        with span("splitft.round.record"):
+            with span("splitft.wait.round"):
+                jax.block_until_ready(metrics)
             rec = self._round_record(r, metrics, plan, cb)
-            self._finish_round(r, rec, log_every, callback)
-            self._pop_scatter()        # cohort rows back to their slots
-        return self.history
+        self._finish_round(r, rec, log_every, callback)
+        if self.store is not None:
+            with span("splitft.round.scatter"):
+                self._pop_scatter()    # cohort rows back to their slots
 
     # ------------------------------------------------------------------
     # async (FedBuff) host loop: event-queue simulation, no barrier

@@ -6,7 +6,10 @@
 
 Thin CLI over runtime.serving.ServingEngine: `build(args)` builds (or
 loads) a stacked per-client adapter pool and synthesizes a Poisson
-request workload; `main` runs the engine and prints latency/throughput.
+request workload; `main` runs the engine and prints latency/throughput
+and where the host's time went: the engine's `serve.*` spans
+(repro.runtime.spans) summed by name — admission with its prefills,
+decode ticks with their wait for the tick's tokens.
 With --ckpt the pool is the SplitFT checkpoint's per-client personalized
 adapters — gathered from PopulationStore slots in population mode, so
 --adapters picks how many fleet members to serve.
@@ -103,12 +106,26 @@ def build(args):
     return engine, reqs
 
 
+def host_phases(records) -> str:
+    """One line: calls and total host ms of each `serve.*` span."""
+    calls, total = {}, {}
+    for name, start, end, _ in records:
+        if name.startswith("serve."):
+            calls[name] = calls.get(name, 0) + 1
+            total[name] = total.get(name, 0.0) + (end - start)
+    return "host time: " + ", ".join(
+        f"{n} {total[n] * 1e3:.1f} ms over {calls[n]}"
+        for n in sorted(total))
+
+
 def main(argv=None):
     args = build_parser().parse_args(argv)
 
     from repro.launch.cache import use_compile_cache
     use_compile_cache()
+    from repro.runtime import spans
     engine, reqs = build(args)
+    spans.reset()
     t0 = time.time()
     results = engine.run(reqs)
     wall = time.time() - t0
@@ -123,6 +140,7 @@ def main(argv=None):
     print(f"latency p50 {np.percentile(lat, 50) * 1e3:.1f} ms   "
           f"p99 {np.percentile(lat, 99) * 1e3:.1f} ms   "
           f"ttft p50 {np.percentile(ttft, 50) * 1e3:.1f} ms")
+    print(host_phases(spans.records()))
     print(f"generated ids (rid 0): {results[0]['tokens'][:16]}")
     return 0
 

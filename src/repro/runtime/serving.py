@@ -38,6 +38,7 @@ import numpy as np
 from repro.core import lora as lora_lib
 from repro.core import split as split_lib
 from repro.runtime import kv_cache
+from repro.runtime.spans import span
 
 Params = Dict[str, Any]
 
@@ -163,11 +164,20 @@ class ServingEngine:
 
     All sampling is greedy (argmax) — the parity contract with the serial
     single-adapter oracle is exact-token equality, so decode is
-    deterministic by construction."""
+    deterministic by construction.
+
+    Latency stamps are seconds of `clock` since the engine's origin (its
+    construction, reset by `run`): `t_submit` is when the request was
+    due, `t_first` when its first token reached the host after the
+    prefill, `t_done` when the tick that produced its last token had
+    returned it to the host."""
 
     def __init__(self, model, params: Params, pool: Params,
-                 cfg: ServeConfig, dtype=jnp.float32):
+                 cfg: ServeConfig, dtype=jnp.float32,
+                 clock=time.perf_counter):
         self.model = model
+        self.clock = clock
+        self.t0 = clock()
         self.params = params
         self.pool = pool
         self.cfg = cfg
@@ -235,10 +245,15 @@ class ServingEngine:
         raise ValueError(f"prompt length {plen} exceeds max bucket "
                          f"{self.cfg.buckets()[-1]}")
 
-    def submit(self, req: Request, *, now: float = 0.0):
-        """Enqueue a request.  Raises immediately (loudly) if the request
-        can never fit the per-slot cache — truncating silently would
-        corrupt the generation."""
+    def now(self) -> float:
+        """Seconds of the engine's clock since its origin."""
+        return self.clock() - self.t0
+
+    def submit(self, req: Request, *, now: Optional[float] = None):
+        """Enqueue a request due at `now` (default: the engine's clock).
+        Raises immediately (loudly) if the request can never fit the
+        per-slot cache — truncating silently would corrupt the
+        generation."""
         plen = int(np.asarray(req.tokens).shape[-1])
         total = plen + req.max_new
         if plen < 1 or req.max_new < 1:
@@ -256,7 +271,8 @@ class ServingEngine:
         self.queue.append(req)
         self.results[req.rid] = {
             "rid": req.rid, "adapter": req.adapter, "prompt_len": plen,
-            "max_new": req.max_new, "t_submit": now,
+            "max_new": req.max_new,
+            "t_submit": self.now() if now is None else now,
             "t_first": None, "t_done": None, "tokens": None}
 
     def _free_slot_ids(self) -> List[int]:
@@ -265,7 +281,7 @@ class ServingEngine:
     def has_work(self) -> bool:
         return bool(self.queue) or any(s is not None for s in self.slots)
 
-    def _admit(self, now: float) -> bool:
+    def _admit(self) -> bool:
         admitted = False
         free = self._free_slot_ids()
         while self.queue and free:
@@ -282,22 +298,26 @@ class ServingEngine:
                 pages = self.allocator.alloc(n_alloc)
             self.queue.popleft()
             slot = free.pop(0)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :plen] = np.asarray(req.tokens, np.int32)
-            tok0, temp = self._prefill(self.params, self.pool,
-                                       jnp.asarray([req.adapter],
-                                                   jnp.int32),
-                                       jnp.asarray(toks),
-                                       jnp.int32(plen))
-            if self.allocator is not None:
-                row = jnp.asarray(kv_cache.page_row(pages, self._p_max))
-                self.cache = self._install_paged(
-                    self.cache, jnp.int32(slot), temp, row,
-                    jnp.int32(plen))
-            else:
-                self.cache = self._install_contig(
-                    self.cache, jnp.int32(slot), temp, jnp.int32(plen))
-            tok0 = int(tok0)
+            with span("serve.prefill", rid=req.rid):
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :plen] = np.asarray(req.tokens, np.int32)
+                tok0, temp = self._prefill(self.params, self.pool,
+                                           jnp.asarray([req.adapter],
+                                                       jnp.int32),
+                                           jnp.asarray(toks),
+                                           jnp.int32(plen))
+                if self.allocator is not None:
+                    row = jnp.asarray(kv_cache.page_row(pages,
+                                                        self._p_max))
+                    self.cache = self._install_paged(
+                        self.cache, jnp.int32(slot), temp, row,
+                        jnp.int32(plen))
+                else:
+                    self.cache = self._install_contig(
+                        self.cache, jnp.int32(slot), temp,
+                        jnp.int32(plen))
+                tok0 = int(tok0)
+            now = self.now()
             res = self.results[req.rid]
             res["t_first"] = now
             state = {"rid": req.rid, "aid": req.adapter, "last": tok0,
@@ -321,13 +341,19 @@ class ServingEngine:
             self.allocator.free(state["pages"])
         self.slots[slot] = None
 
-    def step(self, now: float = 0.0) -> bool:
+    def step(self) -> bool:
         """One engine iteration: admit what fits, then one decode tick
         over all occupied slots.  Returns whether anything ran."""
-        admitted = self._admit(now)
+        with span("serve.admit"):
+            admitted = self._admit()
         occupied = [i for i, s in enumerate(self.slots) if s is not None]
         if not occupied:
             return admitted
+        with span("serve.tick"):
+            self._tick(occupied)
+        return True
+
+    def _tick(self, occupied: List[int]):
         b = self.cfg.num_slots
         toks = np.zeros((b, 1), np.int32)
         ids = np.zeros((b,), np.int32)
@@ -339,7 +365,9 @@ class ServingEngine:
         nxt, self.cache = self._decode(self.params, self.pool,
                                        jnp.asarray(ids), jnp.asarray(toks),
                                        self.cache, jnp.asarray(active))
-        nxt = np.asarray(nxt)
+        with span("serve.wait.tick"):
+            nxt = np.asarray(nxt)
+        now = self.now()
         for i in occupied:
             s = self.slots[i]
             tok = int(nxt[i])
@@ -348,7 +376,6 @@ class ServingEngine:
             s["remaining"] -= 1
             if s["remaining"] <= 0:
                 self._finish(i, now)
-        return True
 
     # -- driver ----------------------------------------------------------
 
@@ -356,16 +383,16 @@ class ServingEngine:
         """Serve a workload honoring per-request arrival offsets; returns
         per-request result dicts (tokens + timing) ordered by rid."""
         reqs = sorted(requests, key=lambda r: (r.arrival, r.rid))
-        t0 = time.perf_counter()
+        self.t0 = self.clock()
         i = 0
         while i < len(reqs) or self.has_work():
-            now = time.perf_counter() - t0
+            now = self.now()
             while i < len(reqs) and reqs[i].arrival <= now:
-                self.submit(reqs[i], now=now)
+                self.submit(reqs[i], now=reqs[i].arrival)
                 i += 1
-            ran = self.step(now=time.perf_counter() - t0)
+            ran = self.step()
             if not ran and not self.has_work() and i < len(reqs):
-                wait = reqs[i].arrival - (time.perf_counter() - t0)
+                wait = reqs[i].arrival - self.now()
                 if wait > 0:
                     time.sleep(min(wait, 0.002))
         return [self.results[r.rid]

@@ -165,6 +165,71 @@ def test_engine_pool_permutation_invariance(setup):
         assert r["tokens"] == want[r["rid"]]
 
 
+def test_latency_stamps_follow_the_engine_clock(setup):
+    """t_submit is when the request was due, t_first when its first token
+    reached the host after the prefill, t_done when the tick that made
+    its last token returned; the injected clock advances 1 s per prefill
+    and 10 s per decode tick."""
+    from repro.runtime import spans
+    model, params, pool = setup
+    clock = {"t": 100.0}
+    eng = serving.ServingEngine(
+        model, params, pool, serving.ServeConfig(num_slots=1, max_len=16),
+        clock=lambda: clock["t"])
+
+    def advancing(fn, dt):
+        def call(*a):
+            out = fn(*a)
+            clock["t"] += dt
+            return out
+        return call
+
+    eng._prefill = advancing(eng._prefill, 1.0)
+    eng._decode = advancing(eng._decode, 10.0)
+    reqs = [serving.Request(rid=0, adapter=0, tokens=np.arange(3, 7),
+                            max_new=3, arrival=0.0),
+            serving.Request(rid=1, adapter=1, tokens=np.arange(5, 9),
+                            max_new=2, arrival=5.0)]
+    spans.reset()
+    res = eng.run(reqs)
+    stamps = [(r["t_submit"], r["t_first"], r["t_done"]) for r in res]
+    # rid 0: due 0, prefill ends at 1, its third token at 1 + 2 ticks;
+    # rid 1: due at 5 though first seen at 11, admitted when rid 0 frees
+    # its slot at 21, prefill ends at 22, its second token at 32
+    assert stamps == [(0.0, 1.0, 21.0), (5.0, 22.0, 32.0)]
+    assert res[0]["tokens"] and len(res[1]["tokens"]) == 2
+    recs = spans.records()
+    names = {r.extra["id"]: r.name for r in recs}
+    pairs = {(r.name, names.get(r.extra["parent"])) for r in recs
+             if r.name.startswith("serve.")}
+    assert pairs == {("serve.admit", None), ("serve.tick", None),
+                     ("serve.prefill", "serve.admit"),
+                     ("serve.wait.tick", "serve.tick")}
+    assert sorted(r.extra["rid"] for r in recs
+                  if r.name == "serve.prefill") == [0, 1]
+    spans.reset()
+
+
+def test_serve_cli_prints_host_time_by_span(capsys):
+    """`repro.launch.serve` reports the engine's spans: admission with its
+    prefills, ticks with their waits, one prefill per request."""
+    from repro.launch import serve as serve_cli
+    assert serve_cli.main(["--reduced", "--adapters", "2", "--requests",
+                           "3", "--num-slots", "2", "--prompt-len", "8",
+                           "--gen", "3"]) == 0
+    line = [ln for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("host time: ")]
+    assert len(line) == 1
+    parts = {}
+    for part in line[0][len("host time: "):].split(", "):
+        name, rest = part.split(" ", 1)
+        parts[name] = int(rest.rsplit(" over ", 1)[1])
+    assert set(parts) == {"serve.admit", "serve.prefill", "serve.tick",
+                          "serve.wait.tick"}
+    assert parts["serve.prefill"] == 3
+    assert parts["serve.tick"] == parts["serve.wait.tick"] >= 3
+
+
 # ---------------------------------------------------------------------------
 # Slot churn: free/admit round-trip is surgical
 
