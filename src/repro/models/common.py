@@ -9,6 +9,7 @@ compress, and ship adapters without touching base weights.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -210,8 +211,15 @@ def apply_norm(p: Params, x, *, kind: str, eps: float):
 # Activations
 
 
+@functools.partial(jax.checkpoint, static_argnums=(2,))
 def activate(x, gate, kind: str):
-    """Apply activation. `gate` is the gate branch for GLU variants (or None)."""
+    """Apply activation. `gate` is the gate branch for GLU variants (or None).
+
+    Checkpointed: under autodiff the backward keeps only the inputs (the
+    MLP's pre-activations) and recomputes the elementwise internals from
+    them, so a scanned layer stack writes one `d_ff`-wide residual per
+    layer, not one per internal of the activation.  Un-differentiated it
+    lowers inline."""
     if kind == "swiglu":
         return jax.nn.silu(gate) * x
     if kind == "geglu":

@@ -79,6 +79,7 @@ def pallas(monkeypatch):
 
 
 def _compile(fn, *shapes):
+    """(program text, memory analysis) of fn compiled for the shapes."""
     compiled = jax.jit(fn).lower(*shapes).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
@@ -86,7 +87,7 @@ def _compile(fn, *shapes):
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     assert used < HBM_BYTES, f"{used / 2 ** 30:.2f} GiB > 16 GiB"
-    return text
+    return text, ma
 
 
 def _flash(dt):
@@ -182,14 +183,24 @@ def _round_args(model, n, batch, *, rep, batch_sh, state_sh=None):
 
 
 def test_gpt2_small_round_compiles_for_one_v5e(one_chip, pallas):
-    """The paper config: 5 clients x batch 4 x seq 512, int8 smashed."""
+    """The paper config: 5 clients x batch 4 x seq 512, int8 smashed.
+
+    The scanned stack saves one d_ff-wide residual per layer (the MLP's
+    pre-activation; the activation is recomputed from it): 8.74 GiB of
+    temporaries; stacking the activation's internals again would take
+    14.36.  No flash forward is recomputed in the backward."""
     model = build_model(get_config("gpt2-small"))
     step = rounds.make_train_step(model, smashed_compress="int8")
-    text = _compile(step, *_round_args(model, 5, 4, rep=one_chip,
-                                       batch_sh=one_chip))
+    text, ma = _compile(step, *_round_args(model, 5, 4, rep=one_chip,
+                                           batch_sh=one_chip))
     for name in ("flash_attention_pallas", "flash_attention_bwd_pallas",
                  "roundtrip_pallas"):
         assert f"jit({name})" in text, name
+    assert ma.temp_size_in_bytes <= 10 * 2 ** 30, \
+        f"{ma.temp_size_in_bytes / 2 ** 30:.2f} GiB of temporaries"
+    assert not [ln for ln in text.splitlines()
+                if "rematted_computation" in ln
+                and "flash_attention_pallas" in ln]
 
 
 def test_sharded_round_compiles_for_four_v5e(topo, pallas):
@@ -206,5 +217,5 @@ def test_sharded_round_compiles_for_four_v5e(topo, pallas):
         batch_sh=NamedSharding(mesh, P("data")),
         state_sh=lambda st: rules.shardings_for(rules.state_specs(st, mesh),
                                                 mesh))
-    text = _compile(step, *args)
+    text, _ = _compile(step, *args)
     assert "all-gather" not in text
