@@ -37,11 +37,11 @@ def _ctx(workload, seed, seconds, overrides):
                                               overrides.get("bench"))
     cfg = overrides.get("cfg", cfg)
     traffic = overrides.get("traffic", traffic)
-    return {"cell": cell, "cfg": cfg, "traffic": traffic,
-            "dims": harness.model_dims(cfg), "seed": seed,
-            "pseed": harness.program_seed(seed), "seconds": seconds,
-            "trace": False, "spans": harness.Spans(), "fault": None,
-            "chips": cell["chips"],
+    dims = harness.model_dims(cfg, overrides.get("families", ()))
+    return {"cell": cell, "cfg": cfg, "traffic": traffic, "dims": dims,
+            "seed": seed, "pseed": harness.program_seed(seed),
+            "seconds": seconds, "trace": False, "spans": harness.Spans(),
+            "fault": None, "chips": cell["chips"],
             "memory_peak": lambda: harness.memory_peak_bytes(cell["chips"]),
             "start_trace": lambda: None, "stop_trace": lambda: None}
 

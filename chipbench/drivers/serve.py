@@ -21,6 +21,7 @@ import numpy as np
 
 from chipbench import arrivals, compare, flops, weights
 from chipbench.harness import TracedSegment, check_program_arch, log
+from chipbench.reference import static_ranks
 
 FAULTS = ("token_altered",)
 DRAIN_S = 60.0
@@ -125,16 +126,16 @@ def open_loop(ctx, engine, due):
 
     # what the traced segment saw, for the per-layer readers
     plens = {d.rid: len(d.tokens) for d in due}
-    lora_sum = 4 * sum(_ranks(dims, ctx["cfg"]["lora"]))
+    ranks = static_ranks(dims, ctx["cfg"]["lora"])
     attended, fl = [], 0.0
     for t, rid, j in tokens:
         if not seg.covers(t):
             continue
         if j == 0:
-            fl += flops.prefill_flops(dims, plens[rid], lora_sum)
+            fl += flops.prefill_flops(dims, plens[rid], ranks)
         else:
             attended.append(plens[rid] + j)
-            fl += flops.decode_flops(dims, plens[rid] + j, lora_sum)
+            fl += flops.decode_flops(dims, plens[rid] + j, ranks)
     return {"emitted": emitted, "late": np.asarray(late),
             "in_window": sum(1 for t, _, _ in tokens if t - t0 <= seconds),
             "end_s": time.perf_counter() - t0, "trace_dir": seg.dir,
@@ -201,12 +202,6 @@ def _p95(values):
     return math.inf if math.isnan(v) else v
 
 
-def _ranks(dims, lora):
-    cut = lora["cut_layer"]
-    return [lora["r_cut"] if l in (cut - 1, cut) else lora["r_others"]
-            for l in range(dims["layers"])]
-
-
 def _new_tokens(engine, counts):
     """{rid: [indices of tokens emitted since the last look]}."""
     now = {}
@@ -251,17 +246,6 @@ def _sample(due, done, k, seed):
     return [longest] + [rest[j] for j in sorted(idx)]
 
 
-def pool_rows(pool, ids):
-    """The harness pool's adapters for rows `ids`, reference layout."""
-    import jax.numpy as jnp
-    out = {}
-    for t, ad in pool["dec"].items():
-        out[t] = {"A": jnp.moveaxis(ad["A"][:, ids], 1, 0),
-                  "B": jnp.moveaxis(ad["B"][:, ids], 1, 0),
-                  "scale": jnp.moveaxis(ad["scale"][:, ids], 1, 0)}
-    return out
-
-
 def reference_readout(base, pool, pick, served, dims, max_len, dtype=None,
                       at=None, block=4):
     """The reference at each position of each picked request that
@@ -276,8 +260,8 @@ def reference_readout(base, pool, pick, served, dims, max_len, dtype=None,
     import jax.numpy as jnp
     from chipbench import reference as R
 
-    def readout(params, rows, toks, want):
-        logits = R.serve_logits(params, rows, toks, dims=dims,
+    def readout(params, pool, ids, toks, want):
+        logits = R.serve_logits(params, pool, ids, toks, dims=dims,
                                 dtype=dtype or jnp.float32)
         top2 = jax.lax.top_k(logits, 2)[0]
         got = jnp.take_along_axis(logits, want[..., None], -1)[..., 0]
@@ -300,7 +284,7 @@ def reference_readout(base, pool, pick, served, dims, max_len, dtype=None,
                                          else np.asarray(at[d.rid], np.int32))
             ids[r] = d.adapter
         gap, top, margin = (np.asarray(a) for a in f(
-            base, pool_rows(pool, jnp.asarray(ids)), jnp.asarray(toks),
+            base, pool, jnp.asarray(ids), jnp.asarray(toks),
             jnp.asarray(want)))
         for r, d in enumerate(part):
             sl = slice(len(d.tokens) - 1, len(d.tokens) - 1

@@ -21,6 +21,7 @@ import numpy as np
 from chipbench import compare, flops, weights
 from chipbench.harness import (BenchError, ROOT, TracedSegment,
                                check_program_arch, log)
+from chipbench.reference import static_ranks
 
 FAULTS = ("state_unchanged", "half_batch", "no_exchange")
 
@@ -83,7 +84,7 @@ def run(ctx):
     system.base_params = base
     system.state = dict(system.state, client_adapters=cad0,
                         server_adapters=sad0)
-    start = {"cad": _host(cad0)["dec"], "sad": _host(sad0)["dec"]}
+    start = {"cad": _host(cad0), "sad": _host(sad0)}
     del cad0, sad0
 
     inner_train, inner_eval = system.train_step, system.eval_step
@@ -130,11 +131,11 @@ def run(ctx):
     for r in range(n_ref):
         system.run(1, log_every=0)
         if r == 0:
-            prog["m1"] = {"cad": _host(system.state["opt_c"]["m"])["dec"],
-                          "sad": _host(system.state["opt_s"]["m"])["dec"]}
+            prog["m1"] = {"cad": _host(system.state["opt_c"]["m"]),
+                          "sad": _host(system.state["opt_s"]["m"])}
     jax.block_until_ready(system.state)
-    prog["after"] = {"cad": _host(system.state["client_adapters"])["dec"],
-                     "sad": _host(system.state["server_adapters"])["dec"]}
+    prog["after"] = {"cad": _host(system.state["client_adapters"]),
+                     "sad": _host(system.state["server_adapters"])}
     prog["losses"] = [h["loss"] for h in system.history[:n_ref]]
     seen["record"] = False
     caches = (inner_train._cache_size(), inner_eval._cache_size())
@@ -179,7 +180,7 @@ def run(ctx):
         "counters": {
             "traced_s": seg.length, "rounds": len(traced),
             "model_flops": flops.train_flops(
-                dims, _static_ranks(dims, lora), lens),
+                dims, static_ranks(dims, lora), lens),
             "rows": tr["clients"] * tr["batch"], "seq_len": tr["seq_len"],
         },
     }
@@ -198,13 +199,6 @@ def run(ctx):
     out["check"] = compare.train_numbers(prog, ref)
     log(f"reference: {n_ref} rounds in {time.perf_counter() - t_ref:.1f} s")
     return out
-
-
-def _static_ranks(dims, lora):
-    cut = lora["cut_layer"]
-    sides = (cut - 1, cut) if lora["two_side_cut"] else (cut - 1,)
-    return [lora["r_cut"] if l in sides else lora["r_others"]
-            for l in range(dims["layers"])]
 
 
 def reference_rounds(base, start, inputs, dims, cfg, dtype=None,

@@ -4,7 +4,8 @@ Model FLOPs count what the mathematics requires, so the same work reads
 the same whatever implements it:
 
 * a frozen-base LoRA training token: the forward pass and the input
-  gradient of every base matmul and of the tied LM head, attention scores
+  gradient of every base matmul and of the LM head (the family's `macs`
+  and `head_macs`; of routed experts the active ones), attention scores
   and values under the layer's mask (forward 2 matmuls, backward 4), and
   the LoRA terms forward (2) and backward (4).  Frozen-weight gradients,
   recomputed ops and the C3 evaluation forward are left out.
@@ -35,70 +36,95 @@ def mean_kept_pairs(s, windows):
     return float(np.mean([kept_keys(s, w) for w in windows]))
 
 
-def _matmul_macs_per_token(dims):
-    d, ff = dims["d_model"], dims["d_ff"]
-    return 4 * d * d + 2 * d * ff
+def attn_groups(dims):
+    """{(heads, kv_heads, qk_dim, v_dim): [window of each layer of that
+    attention shape]}, from the family's per-layer attention."""
+    out = {}
+    for lay in dims["layer"]:
+        a = lay["attn"]
+        key = (a["heads"], a["kv_heads"], a["qk_dim"], a["v_dim"])
+        out.setdefault(key, []).append(a["window"])
+    return out
+
+
+def _attn_macs(lay):
+    """Multiply-adds per kept (query, key) pair: q k^T and p v."""
+    a = lay["attn"]
+    return a["heads"] * (a["qk_dim"] + a["v_dim"])
+
+
+def _lora_macs(dims, ranks):
+    """LoRA multiply-adds per token, forward: x A and (x A) B of every
+    target of every layer at its rank."""
+    return sum(int(r) * sum(di + do for di, do in lay["targets"].values())
+               for r, lay in zip(ranks, dims["layer"]))
+
+
+def _base_macs(dims):
+    return sum(lay["macs"] for lay in dims["layer"])
 
 
 def train_flops(dims, lora_ranks, real_lengths):
     """Model FLOPs of one training pass over rows with `real_lengths`
     real tokens each (pads cost nothing here).  lora_ranks: (L,) the
-    effective rank of each layer (targets q, k, v, o)."""
+    effective rank of each layer."""
     lens = np.asarray(real_lengths, np.int64).ravel()
     tokens = int(lens.sum())
-    d, v, L = dims["d_model"], dims["vocab"], dims["layers"]
-    base = 4 * L * _matmul_macs_per_token(dims)          # fwd 2 + dx 2
-    head = 4 * d * v
-    lora = 12 * 4 * d * int(np.sum(lora_ranks))           # 4 targets
-    pairs = sum(int(np.sum(kept_keys(lens, w))) for w in dims["windows"])
-    attn = 12 * d * pairs                                 # (2 + 4) matmuls
+    base = 4 * _base_macs(dims)                         # fwd 2 + dx 2
+    head = 4 * dims["head_macs"]
+    lora = 6 * _lora_macs(dims, lora_ranks)             # fwd 2 + bwd 4
+    attn = sum(6 * _attn_macs(lay)                      # (2 + 4) matmuls
+               * int(np.sum(kept_keys(lens, lay["attn"]["window"])))
+               for lay in dims["layer"])
     return tokens * (base + head + lora) + attn
 
 
-def prefill_flops(dims, n, lora_rank_sum):
+def prefill_flops(dims, n, lora_ranks):
     """Forward FLOPs of one prompt of n tokens, logits of the last only."""
-    d, v = dims["d_model"], dims["vocab"]
-    per_tok = 2 * dims["layers"] * _matmul_macs_per_token(dims) \
-        + 4 * 4 * d * lora_rank_sum
-    pairs = sum(int(kept_keys(n, w)) for w in dims["windows"])
-    return n * per_tok + 4 * d * pairs + 2 * d * v
+    per_tok = 2 * _base_macs(dims) + 2 * _lora_macs(dims, lora_ranks)
+    attn = sum(2 * _attn_macs(lay) * int(kept_keys(n, lay["attn"]["window"]))
+               for lay in dims["layer"])
+    return n * per_tok + attn + 2 * dims["head_macs"]
 
 
-def decode_flops(dims, attended, lora_rank_sum):
+def decode_flops(dims, attended, lora_ranks):
     """Forward FLOPs of one decoded token that attends over `attended`
     positions (itself included) in every layer."""
-    d, v = dims["d_model"], dims["vocab"]
-    per_tok = 2 * dims["layers"] * _matmul_macs_per_token(dims) \
-        + 4 * 4 * d * lora_rank_sum + 2 * d * v
-    keys = sum(min(attended, w) if w > 0 else attended
-               for w in dims["windows"])
-    return per_tok + 4 * d * keys
+    per_tok = 2 * _base_macs(dims) + 2 * _lora_macs(dims, lora_ranks) \
+        + 2 * dims["head_macs"]
+    attn = 0
+    for lay in dims["layer"]:
+        w = lay["attn"]["window"]
+        attn += 2 * _attn_macs(lay) * (min(attended, w) if w > 0
+                                       else attended)
+    return per_tok + attn
 
 
-def flash_fwd(rows, s, heads, head_dim, pairs, itemsize=4):
+def flash_fwd(rows, s, heads, qk_dim, v_dim, pairs, itemsize=4):
     """(flops, bytes) of one causal flash forward over `rows` sequences of
-    length s with `pairs` kept pairs per row and head."""
-    flops = 4 * rows * heads * head_dim * pairs
-    act = rows * s * heads * head_dim * itemsize
+    length s with `pairs` kept pairs per row and head: q k^T over qk_dim,
+    p v over v_dim."""
+    flops = 2 * rows * heads * (qk_dim + v_dim) * pairs
+    act = rows * s * heads * (2 * qk_dim + 2 * v_dim) * itemsize
     lse = rows * heads * s * 4
-    return flops, 4 * act + lse                 # q, k, v in; o out
+    return flops, act + lse                     # q, k, v in; o out
 
 
-def flash_bwd(rows, s, heads, head_dim, pairs, itemsize=4):
-    """(flops, bytes) of the flash backward: scores rebuilt, dP, dV, dQ,
-    dK (5 matmuls over the kept pairs)."""
-    flops = 10 * rows * heads * head_dim * pairs
-    act = rows * s * heads * head_dim * itemsize
+def flash_bwd(rows, s, heads, qk_dim, v_dim, pairs, itemsize=4):
+    """(flops, bytes) of the flash backward: scores rebuilt, dQ, dK over
+    qk_dim; dP, dV over v_dim (5 matmuls over the kept pairs)."""
+    flops = 2 * rows * heads * (3 * qk_dim + 2 * v_dim) * pairs
+    act = rows * s * heads * (4 * qk_dim + 4 * v_dim) * itemsize
     lse = rows * heads * s * 4
-    return flops, 8 * act + lse     # q k v o do in; dq dk dv out
+    return flops, act + lse         # q k v o do in; dq dk dv out
 
 
-def decode_attention(attended, heads, kv_heads, head_dim, itemsize=4):
+def decode_attention(attended, heads, kv_heads, qk_dim, v_dim, itemsize=4):
     """(flops, bytes) of decode attention for one query row attending
     over `attended` cached positions: q K^T and p V; K and V read once."""
-    flops = 4 * heads * head_dim * attended
-    byts = 2 * kv_heads * head_dim * attended * itemsize \
-        + 2 * heads * head_dim * itemsize
+    flops = 2 * heads * (qk_dim + v_dim) * attended
+    byts = kv_heads * (qk_dim + v_dim) * attended * itemsize \
+        + heads * (qk_dim + v_dim) * itemsize
     return flops, byts
 
 

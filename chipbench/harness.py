@@ -1,5 +1,6 @@
-"""What every cell shares: the cell's files found by name, the device
-guard, the compile cache, host spans, and the per-layer metric readers."""
+"""What every cell shares: the cell's files and model family found by
+name, the device guard, the compile cache, host spans, and the per-layer
+metric readers."""
 
 from __future__ import annotations
 
@@ -37,42 +38,31 @@ def load_cell(name, bench=None):
     return cell, cfg, traffic, bench
 
 
-def model_dims(cfg):
-    """The sizes the harness and the reference use, read from the
-    published keys of the configuration file."""
-    if cfg["model_type"] == "gpt2":
-        d, L, h = cfg["n_embd"], cfg["n_layer"], cfg["n_head"]
-        ff, pos = cfg["n_inner"] or 4 * d, cfg["n_positions"]
-        windows = [0] * L
-    elif cfg["model_type"] == "gpt_neo":
-        d, L, h = cfg["hidden_size"], cfg["num_layers"], cfg["num_heads"]
-        ff, pos = cfg["intermediate_size"] or 4 * d, \
-            cfg["max_position_embeddings"]
-        kinds = [k for pattern, reps in cfg["attention_types"]
-                 for _ in range(reps) for k in pattern]
-        windows = [cfg["window_size"] if k == "local" else 0 for k in kinds]
-    else:
-        raise BenchError(f"unknown model_type {cfg['model_type']!r}")
-    if cfg["activation_function"] != "gelu_new":
-        raise BenchError("the reference implements gelu_new only")
-    att = cfg["attention"]
-    hd = d // h
-    scale = {"1/sqrt(head_dim)": hd ** -0.5, "none": 1.0}[att["scale"]]
-    return {"d_model": d, "layers": L, "heads": h, "head_dim": hd,
-            "d_ff": ff, "vocab": cfg["vocab_size"], "positions": pos,
-            "eps": cfg["layer_norm_epsilon"], "windows": windows,
-            "attn_scale": scale, "qkv_bias": att["qkv_bias"],
-            "out_bias": att["out_bias"]}
+def load_family(model_type, path=()):
+    """The family module of `model_type` (chipbench/families/__init__.py
+    says what it gives): <model_type>.py in the directories of `path`
+    (tests only), then in chipbench/families/."""
+    tried = [pathlib.Path(d) / f"{model_type}.py"
+             for d in (*path, HERE / "families")]
+    for f in tried:
+        if f.exists():
+            return _load_module(f, f"chipbench_family_{model_type}")
+    raise BenchError(f"no family for model_type {model_type!r}: "
+                     f"{', '.join(_shown(f) for f in tried)}")
+
+
+def model_dims(cfg, path=()):
+    """The family's sizes of the configuration file, with the family
+    module itself under "family"."""
+    fam = load_family(cfg["model_type"], path)
+    return dict(fam.dims(cfg), family=fam)
 
 
 def check_program_arch(dims, lora, arch):
     """Raise unless the program's registry entry has the file's sizes."""
-    m = arch.model
-    want = {"d_model": m.d_model, "layers": m.num_layers,
-            "heads": m.num_heads, "d_ff": m.d_ff, "vocab": m.vocab_size,
-            "positions": m.max_position_embeddings, "eps": m.norm_eps,
-            "r_others": arch.lora.r_others, "r_cut": arch.lora.r_cut,
-            "alpha": arch.lora.alpha, "cut_layer": arch.split.cut_layer}
+    want = dict(dims["family"].program_sizes(arch),
+                r_others=arch.lora.r_others, r_cut=arch.lora.r_cut,
+                alpha=arch.lora.alpha, cut_layer=arch.split.cut_layer)
     have = dict(dims, **{k: lora[k] for k in
                          ("r_others", "r_cut", "alpha", "cut_layer")})
     bad = {k: (have[k], v) for k, v in want.items() if have[k] != v}
@@ -233,12 +223,22 @@ def metric_reader(name):
     path = HERE / "metrics" / f"{name}.py"
     if not path.exists():
         raise BenchError(f"no reader for per-layer metric {name!r}: "
-                         f"{path.relative_to(ROOT)}")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}", path)
+                         f"{_shown(path)}")
+    return _load_module(
+        path, f"chipbench_metric_{name.replace('.', '_')}").read
+
+
+def _load_module(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def _shown(path):
+    path = pathlib.Path(path).resolve()
+    return str(path.relative_to(ROOT)) if path.is_relative_to(ROOT) \
+        else str(path)
 
 
 def metrics_for(cell, bench, kind):

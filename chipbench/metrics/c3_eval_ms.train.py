@@ -1,5 +1,5 @@
-"""Device time of the C3 evaluation step (`eval_step`) per round: the
-runs of the smaller `jit_step` module inside the window."""
+"""Device time of the C3 evaluation step per round: the runs of its XLA
+module, `jit_c3_eval_step`, inside the window, over the rounds."""
 
 from chipbench.programs import split_step_modules
 

@@ -1,6 +1,5 @@
 """Device time of the jitted round step per round: the runs of its XLA
-module inside the window (the largest `jit_step` program, the round
-engine; the C3 evaluation step is the smaller one), over the rounds."""
+module, `jit_round_step`, inside the window, over the rounds."""
 
 from chipbench.programs import split_step_modules
 

@@ -2,10 +2,10 @@
 
 Kernels are found by the names `chip_smoke.py` finds in the compiled
 HLO: the Pallas wrapper's name rides in each custom call's metadata.
-The training round and the C3 evaluation step are both jitted functions
-named `step`, so their modules are both `jit_step(<fingerprint>)`; per
-round each runs once, and the round (forward and backward) takes longer
-than the evaluation (forward only).
+The training round and the C3 evaluation step are found by their
+modules' stable names, `jit_round_step` and `jit_c3_eval_step` (the
+jitted functions `round_step` and `c3_eval_step`); per round each runs
+once.
 """
 
 from __future__ import annotations
@@ -15,6 +15,8 @@ FLASH_BWD = "flash_attention_bwd_pallas"
 DECODE_PAGED = "decode_attention_paged_pallas"
 PREFILL_MODULE = "_prefill_raw"
 DECODE_MODULE = "_decode_raw"
+ROUND_MODULE = "jit_round_step"
+C3_MODULE = "jit_c3_eval_step"
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute")
 
@@ -38,21 +40,16 @@ def is_collective(label):
 
 
 def split_step_modules(tr, dev=0):
-    """(round seconds, eval seconds) of the `step` module runs inside the
-    window on one device: of the two compiled `jit_step` programs the
-    one with more device time is the round; None where absent."""
+    """(round seconds, C3 evaluation seconds): the runs of the modules
+    named ROUND_MODULE and C3_MODULE inside the window on one device;
+    None where absent."""
     tot = {}
     for n, s, d in tr.modules[dev]:
-        if _step_name(n) and s >= tr.window[0] and s + d <= tr.window[1]:
-            tot[n] = tot.get(n, 0.0) + d * 1e-9
-    ranked = sorted(tot.values(), reverse=True)
-    return (ranked[0] if ranked else None,
-            ranked[1] if len(ranked) > 1 else None)
-
-
-def _step_name(n):
-    base = n.split("(", 1)[0]
-    return base in ("jit_step", "step") or base.endswith("_step")
+        base = n.split("(", 1)[0]
+        if base in (ROUND_MODULE, C3_MODULE) and s >= tr.window[0] \
+                and s + d <= tr.window[1]:
+            tot[base] = tot.get(base, 0.0) + d * 1e-9
+    return tot.get(ROUND_MODULE), tot.get(C3_MODULE)
 
 
 def module_named(name):

@@ -1,5 +1,7 @@
-"""The plain reference: a GPT-style decoder with LoRA, written from the
-configuration file alone in straightforward `jax.numpy`.
+"""The plain reference: a decoder with LoRA, written from the
+configuration file alone in straightforward `jax.numpy`.  This file holds
+the SplitFT mechanics; the model half (embedding, one block, final norm
+and head) is the family's (chipbench/families/).
 
 It imports nothing of the program.  Weights, adapters and inputs come
 from the harness (made from `--seed`), never from the program.  The
@@ -21,31 +23,13 @@ through its own adapter.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-TARGETS = ("q", "k", "v", "o")
-
-
 # ---------------------------------------------------------------------------
 # building blocks
-
-
-def layer_norm(x, scale, bias, eps):
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, -1, keepdims=True)
-    var = jnp.mean(jnp.square(xf - mu), -1, keepdims=True)
-    y = (xf - mu) / jnp.sqrt(var + eps) * scale.astype(jnp.float32)
-    return (y + bias.astype(jnp.float32)).astype(x.dtype)
-
-
-def gelu_tanh(x):
-    """GPT-2's gelu_new."""
-    c = math.sqrt(2.0 / math.pi)
-    return 0.5 * x * (1.0 + jnp.tanh(c * (x + 0.044715 * x ** 3)))
 
 
 def attention(q, k, v, window, scale):
@@ -72,37 +56,12 @@ def lora_linear(x, w, b, ad):
     return y
 
 
-def block(x, p, ads, window, dims):
-    """One pre-norm GPT block.  p: this layer's weights; ads: target ->
-    (A, B, scale) or None."""
-    h, hd = dims["heads"], dims["head_dim"]
-    lead = x.shape[:-1]
-    y = layer_norm(x, p["ln1_s"], p["ln1_b"], dims["eps"])
-    q = lora_linear(y, p["wq"], p.get("bq"), ads.get("q"))
-    k = lora_linear(y, p["wk"], p.get("bk"), ads.get("k"))
-    v = lora_linear(y, p["wv"], p.get("bv"), ads.get("v"))
-    split = lambda t: t.reshape(lead + (h, hd))         # noqa: E731
-    o = attention(split(q), split(k), split(v), window, dims["attn_scale"])
-    x = x + lora_linear(o.reshape(lead + (h * hd,)), p["wo"], p.get("bo"),
-                        ads.get("o"))
-    y = layer_norm(x, p["ln2_s"], p["ln2_b"], dims["eps"])
-    hmid = gelu_tanh(y @ p["w_in"] + p["b_in"])
-    return x + hmid @ p["w_out"] + p["b_out"]
-
-
-def layer_params(params, l):
-    """The harness weight tree (program layout, layer-stacked) at layer l,
-    under the reference's own names."""
-    dec = params["dec"]
-    p = {"ln1_s": dec["norm1"]["scale"][l], "ln1_b": dec["norm1"]["bias"][l],
-         "ln2_s": dec["norm2"]["scale"][l], "ln2_b": dec["norm2"]["bias"][l],
-         "wq": dec["wq"][l], "wk": dec["wk"][l], "wv": dec["wv"][l],
-         "wo": dec["wo"][l], "w_in": dec["w_in"][l], "w_out": dec["w_out"][l],
-         "b_in": dec["b_in"][l], "b_out": dec["b_out"][l]}
-    for nm in ("bq", "bk", "bv", "bo"):
-        if nm in dec:
-            p[nm] = dec[nm][l]
-    return p
+def layer_groups(dims):
+    """{group: [its layers, in order]}: the program's layer stacks."""
+    out = {}
+    for l, lay in enumerate(dims["layer"]):
+        out.setdefault(lay["group"], []).append(l)
+    return out
 
 
 def cast(tree, dtype):
@@ -149,34 +108,41 @@ def layer_ranks(dims, lora, cut):
     return jnp.where(at_cut, lora["r_cut"], lora["r_others"])
 
 
+def static_ranks(dims, lora):
+    """[effective rank of each layer] at the configured cut."""
+    cut = lora["cut_layer"]
+    sides = (cut - 1, cut) if lora["two_side_cut"] else (cut - 1,)
+    return [lora["r_cut"] if l in sides else lora["r_others"]
+            for l in range(dims["layers"])]
+
+
 def client_loss(params, cad, sad, batch, cut, *, dims, lora, compress):
     """One client's mean next-token loss over its real tokens.
 
-    cad / sad: {target: {"A": (L, d, r), "B": (L, r, d)}} client and
-    server adapters; layer l < cut takes the client's, the rest the
-    server's.  batch: tokens, labels, loss_mask, each (B, S)."""
-    dt = params["embed"]["tok"].dtype
-    toks = batch["tokens"]
-    s = toks.shape[-1]
-    x = params["embed"]["tok"][toks] + params["embed"]["pos"][:s]
+    cad / sad: {group: {target: {"A": (L_g, d_in, r), "B": (L_g, r,
+    d_out)}}} client and server adapters; layer l < cut takes the
+    client's, the rest the server's.  batch: tokens, labels, loss_mask,
+    each (B, S)."""
+    fam = dims["family"]
+    dt = jax.tree.leaves(params)[0].dtype
+    x = fam.embed(params, batch["tokens"], dims)
     ranks = layer_ranks(dims, lora, cut)
     r_max = lora["r_others"]
-    for l in range(dims["layers"]):
+    for l, lay in enumerate(dims["layer"]):
         own = l < cut
         rk = ranks[l]
         cmask = (jnp.arange(r_max) < rk).astype(dt)
         sc = (lora["alpha"] / rk).astype(dt)
+        c, s, j = cad[lay["group"]], sad[lay["group"]], lay["index"]
         ads = {}
-        for t in TARGETS:
-            a = jnp.where(own, cad[t]["A"][l], sad[t]["A"][l]) * cmask
-            b = jnp.where(own, cad[t]["B"][l], sad[t]["B"][l]) * cmask[:, None]
+        for t in lay["targets"]:
+            a = jnp.where(own, c[t]["A"][j], s[t]["A"][j]) * cmask
+            b = jnp.where(own, c[t]["B"][j], s[t]["B"][j]) * cmask[:, None]
             ads[t] = (a, b, sc)
-        x = block(x, layer_params(params, l), ads, dims["windows"][l], dims)
+        x = fam.block(x, params, l, ads, dims)
         if compress == "int8":
             x = jnp.where(l == cut - 1, smashed(x), x)
-    x = layer_norm(x, params["final_norm"]["scale"],
-                   params["final_norm"]["bias"], dims["eps"])
-    logits = (x @ params["embed"]["tok"].T).astype(jnp.float32)
+    logits = fam.head(params, x, dims)
     lse = jax.nn.logsumexp(logits, -1)
     gold = jnp.take_along_axis(logits, batch["labels"][..., None], -1)[..., 0]
     m = batch["loss_mask"].astype(jnp.float32)
@@ -213,8 +179,9 @@ def round_grads(params, cad, sad, batch, cuts, wl, *, dims, lora, compress,
     client i runs on devices[i % len(devices)] (its inputs are put
     there), so on several chips the clients run side by side.
 
-    cad: client adapters {t: {"A": (L, N, d, r), ...}}; batch arrays
-    (N, B, S).  Returns (total, per-client losses, g_cad, g_sad)."""
+    cad: client adapters {g: {t: {"A": (L_g, N, d_in, r), ...}}}; batch
+    arrays (N, B, S).  Returns (total, per-client losses, g_cad,
+    g_sad)."""
     key = _static_key(dims, lora, compress)
     devices = devices or [None]
     home = jax.tree.leaves(params)[0].devices().pop()
@@ -263,21 +230,24 @@ def adam(p, g, st, *, lr, b1, b2, eps, clip):
     return p, (m, v, t), g
 
 
-def fedavg(cad, sad, cuts, w, layers):
+def fedavg(cad, sad, cuts, w, dims):
     """Each layer's client rows -> the weighted mean over the clients that
     own it; rows of clients that do not own it mirror the server."""
-    own = (np.arange(layers)[:, None] < np.asarray(cuts)[None, :])  # (L, N)
-    mu = own * np.asarray(w, np.float64)[None, :]
-    den = np.maximum(mu.sum(1), 1e-9)
-    own_j = jnp.asarray(own, jnp.float32)
-    mu_j = jnp.asarray(mu / den[:, None], jnp.float32)
+    out = {}
+    for g, ls in layer_groups(dims).items():
+        own = (np.asarray(ls)[:, None] < np.asarray(cuts)[None, :])  # (L_g, N)
+        mu = own * np.asarray(w, np.float64)[None, :]
+        den = np.maximum(mu.sum(1), 1e-9)
+        own_j = jnp.asarray(own, jnp.float32)
+        mu_j = jnp.asarray(mu / den[:, None], jnp.float32)
 
-    def one(c, s):
-        agg = jnp.einsum("ln,ln...->l...", mu_j, c)
-        o = own_j.reshape(own.shape + (1,) * (c.ndim - 2))
-        return o * agg[:, None] + (1 - o) * s[:, None]
+        def one(c, s, own=own, own_j=own_j, mu_j=mu_j):
+            agg = jnp.einsum("ln,ln...->l...", mu_j, c)
+            o = own_j.reshape(own.shape + (1,) * (c.ndim - 2))
+            return o * agg[:, None] + (1 - o) * s[:, None]
 
-    return jax.tree.map(one, cad, sad)
+        out[g] = jax.tree.map(one, cad[g], sad[g])
+    return out
 
 
 def train_round(params, state, batch, cuts, weights, active, *, dims, lora,
@@ -301,7 +271,7 @@ def train_round(params, state, batch, cuts, weights, active, *, dims, lora,
     k = len(cuts) // groups
     parts = [fedavg(jax.tree.map(lambda c: c[:, g * k:(g + 1) * k], cad),
                     sad, cuts[g * k:(g + 1) * k], w[g * k:(g + 1) * k],
-                    dims["layers"]) for g in range(groups)]
+                    dims) for g in range(groups)]
     cad = jax.tree.map(lambda *p: jnp.concatenate(p, 1), *parts)
     new = dict(cad=cad, sad=sad, opt_c=opt_c, opt_s=opt_s)
     return new, total, g_c, g_s
@@ -316,49 +286,19 @@ def init_opt(tree):
 # serving
 
 
-def serve_logits(params, pool_rows, tokens, *, dims, dtype=jnp.float32):
-    """Logits (R, T, V) of rows `tokens` (R, T), row r through its own
-    adapter: pool_rows {t: {"A": (R, L, d, r), "B": (R, L, r, d),
-    "scale": (R, L)}}."""
+def serve_logits(params, pool, ids, tokens, *, dims, dtype=jnp.float32):
+    """Logits (R, T, V) of rows `tokens` (R, T), row r through adapter
+    ids[r] of the pool {group: {target: {"A": (L_g, n, d_in, r), "B":
+    (L_g, n, r, d_out), "scale": (L_g, n)}}}."""
     precision = "highest" if dtype == jnp.float32 else "default"
     with jax.default_matmul_precision(precision):
-        params, pool_rows = cast((params, pool_rows), dtype)
-        t = tokens.shape[-1]
-        x = params["embed"]["tok"][tokens] + params["embed"]["pos"][:t]
-        for l in range(dims["layers"]):
-            ads = {}
-            for tg in TARGETS:
-                a = pool_rows[tg]["A"][:, l][:, None]        # (R, 1, d, r)
-                b = pool_rows[tg]["B"][:, l][:, None]
-                sc = pool_rows[tg]["scale"][:, l][:, None, None]
-                ads[tg] = (a, b, sc)
-            x = _row_block(x, layer_params(params, l), ads,
-                           dims["windows"][l], dims)
-        x = layer_norm(x, params["final_norm"]["scale"],
-                       params["final_norm"]["bias"], dims["eps"])
-        return (x @ params["embed"]["tok"].T).astype(jnp.float32)
-
-
-def _row_block(x, p, ads, window, dims):
-    """`block` with per-row adapters: x (R, T, d); A (R, 1, d, r)."""
-    def lin(y, w, b, ad):
-        out = y @ w
-        if b is not None:
-            out = out + b
-        a, bb, sc = ad
-        xa = jnp.einsum("rtd,rkdq->rtq", y, a)
-        return out + (sc * jnp.einsum("rtq,rkqe->rte", xa, bb)).astype(
-            out.dtype)
-
-    h, hd = dims["heads"], dims["head_dim"]
-    lead = x.shape[:-1]
-    y = layer_norm(x, p["ln1_s"], p["ln1_b"], dims["eps"])
-    q = lin(y, p["wq"], p.get("bq"), ads["q"])
-    k = lin(y, p["wk"], p.get("bk"), ads["k"])
-    v = lin(y, p["wv"], p.get("bv"), ads["v"])
-    split = lambda t: t.reshape(lead + (h, hd))         # noqa: E731
-    o = attention(split(q), split(k), split(v), window, dims["attn_scale"])
-    x = x + lin(o.reshape(lead + (h * hd,)), p["wo"], p.get("bo"), ads["o"])
-    y = layer_norm(x, p["ln2_s"], p["ln2_b"], dims["eps"])
-    hmid = gelu_tanh(y @ p["w_in"] + p["b_in"])
-    return x + hmid @ p["w_out"] + p["b_out"]
+        params, pool = cast((params, pool), dtype)
+        fam = dims["family"]
+        x = fam.embed(params, tokens, dims)
+        for l, lay in enumerate(dims["layer"]):
+            p, j = pool[lay["group"]], lay["index"]
+            ads = {t: (p[t]["A"][j][ids], p[t]["B"][j][ids],
+                       p[t]["scale"][j][ids][:, None, None])
+                   for t in lay["targets"]}
+            x = fam.block(x, params, l, ads, dims)
+        return fam.head(params, x, dims)

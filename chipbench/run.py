@@ -5,8 +5,9 @@
       --trace <0|1>
 
 The cell is an entry of BENCHMARK.json's `workloads`; its configuration
-file, its traffic file (which names the driver under drivers/) and its
-per-layer readers (metrics/<name>.py) are found by name.  The run loads,
+file, its model family (families/<model_type>.py), its traffic file
+(which names the driver under drivers/) and its per-layer readers
+(metrics/<name>.py) are found by name.  The run loads,
 warms up, measures for --seconds, checks what the timed path produced
 against the plain reference, and prints one JSON line last on stdout:
 the end-to-end metrics with --trace 0, the per-layer metrics with
@@ -80,7 +81,8 @@ def _tracer(ctx, enabled):
 def main(argv=None, *, require_tpu=True, overrides=None):
     """Exit status; the result line is printed only when it is 0.
     overrides (tests only): replace the cell's configuration or traffic,
-    and plant a fault under the timed path."""
+    look for families in more directories first, and plant a fault under
+    the timed path."""
     args = parser().parse_args(argv)
     overrides = overrides or {}
     try:
@@ -98,7 +100,9 @@ def main(argv=None, *, require_tpu=True, overrides=None):
         driver = importlib.import_module(
             f"chipbench.drivers.{traffic['driver']}")
         ctx = {"cell": cell, "cfg": cfg, "traffic": traffic,
-               "dims": harness.model_dims(cfg), "seed": args.seed,
+               "dims": harness.model_dims(cfg,
+                                          overrides.get("families", ())),
+               "seed": args.seed,
                "pseed": harness.program_seed(args.seed),
                "seconds": args.seconds, "trace": bool(args.trace),
                "spans": harness.Spans(), "fault": overrides.get("fault"),

@@ -174,6 +174,6 @@ def test_real_recorder_on_a_tiny_round():
     shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                           seen[0])
     text = inner.lower(*shapes).as_text()
-    assert "@jit_round_step" in text
-    assert programs._step_name("jit_round_step(123)")
-    assert programs._step_name("jit_c3_eval_step(456)")
+    assert f"@{programs.ROUND_MODULE}" in text
+    assert f"@{programs.C3_MODULE}" in system.eval_step.lower(
+        *shapes[:4]).as_text()

@@ -1,6 +1,7 @@
 """The trace reduction, on a small recorded profile (a 53 ms slice of a
-gpt2-small training round on one v5e, trimmed to about 200 device ops)
-and on a hand-made one."""
+gpt2-small training round on one v5e, trimmed to about 200 device ops),
+on the modules and harness spans of a traced window of each training
+cell (one v5e, seed 2718281828), and on a hand-made profile."""
 
 import json
 import pathlib
@@ -12,7 +13,7 @@ import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[2]))
 
-from chipbench import programs, trace  # noqa: E402
+from chipbench import harness, programs, trace  # noqa: E402
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 
@@ -42,7 +43,8 @@ def test_hand_made_profile():
                 ["while.1", 0, 40], ["fusion.3", 0, 10], ["fusion.4", 5, 15],
                 ["flash_attention_pallas.7", 30, 10]]},
             {"name": "XLA Modules", "events": [
-                ["jit_step(1)", 0, 20], ["jit_step(2)", 30, 10]]}]},
+                ["jit_round_step(1)", 0, 20],
+                ["jit_c3_eval_step(2)", 30, 10]]}]},
         {"name": "/host:CPU", "lines": [
             {"name": "python3", "events": [
                 ["bench.traced", 0, 50], ["bench.round", 0, 50],
@@ -84,6 +86,31 @@ def test_recorded_profile():
     kinds = [k for k, _ in tr.top_ops(10)]
     assert "while" not in kinds and kinds
     assert all(not k[-1].isdigit() or "." not in k for k in kinds)
+    # recorded before the steps had their stable names, and no module
+    # run lies whole inside the slice
+    assert programs.split_step_modules(tr) == (None, None)
+
+
+@pytest.mark.parametrize("name,rounds,want", [
+    ("train_modules_gpt2s.json", 17, (2.5479870260000004, 1.22956157)),
+    ("train_modules_neo125.json", 18, (2.3304427290000005, 1.323117253))])
+def test_recorded_steps_are_found_by_name(name, rounds, want):
+    """By name the same modules as by size, the rule before: of the step
+    modules run whole inside the window, the one with more time."""
+    with open(DATA / name) as f:
+        tr = trace.from_planes(_planes(json.load(f)))
+    got = programs.split_step_modules(tr)
+    assert got == pytest.approx(want, rel=1e-12)
+    tot = {}
+    for n, s, d in tr.modules[0]:
+        if n.split("(")[0].endswith("_step") and s >= tr.window[0] \
+                and s + d <= tr.window[1]:
+            tot[n] = tot.get(n, 0.0) + d * 1e-9
+    assert tuple(sorted(tot.values(), reverse=True)) == \
+        pytest.approx(got, rel=1e-12)
+    ctx = {"trace": tr, "counters": {"rounds": rounds}}
+    assert harness.metric_reader("round_device_ms.train")(ctx) == \
+        pytest.approx(1e3 * got[0] / rounds)
 
 
 def test_op_names():

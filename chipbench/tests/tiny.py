@@ -1,9 +1,12 @@
-"""Tiny stand-ins of the cells, for runs on the CPU in the tests: the
-program's own `--reduced` sizes (2 layers, width 64, vocabulary 512)."""
+"""Tiny stand-ins of the cells, for runs on the CPU in the tests: each
+family's `tiny` sizes (the program's own `--reduced` ones: 2 layers,
+width 64, vocabulary 512)."""
 
 import copy
 import json
 import pathlib
+
+from chipbench import harness
 
 HERE = pathlib.Path(__file__).resolve().parent
 BENCH = HERE.parents[1] / "chipbench"
@@ -15,17 +18,9 @@ def _load(path):
 
 
 def tiny_cfg(name="gpt2-small"):
-    cfg = _load(BENCH / "configs" / f"{name}.json")
-    cfg = copy.deepcopy(cfg)
+    cfg = copy.deepcopy(_load(BENCH / "configs" / f"{name}.json"))
+    cfg = harness.load_family(cfg["model_type"]).tiny(cfg)
     cfg["program_reduced"] = True
-    if cfg["model_type"] == "gpt2":
-        cfg.update(n_layer=2, n_embd=64, n_head=4, n_inner=256,
-                   vocab_size=512, n_positions=256, n_ctx=256)
-    else:
-        cfg.update(num_layers=2, hidden_size=64, num_heads=4,
-                   intermediate_size=256, vocab_size=512,
-                   max_position_embeddings=256,
-                   attention_types=[[["global", "local"], 1]], window_size=32)
     cfg["lora"] = dict(cfg["lora"], r_others=4, r_cut=2, cut_layer=1)
     return cfg
 

@@ -1,34 +1,19 @@
 """Weights, adapters and adapter pools made by the harness from `--seed`.
 
 Each tree is made on the device in one jitted call, in the layout the
-program takes (layer-stacked, `dec` group), from the configuration
-file's sizes alone.  The program and the reference are both handed
-these arrays, so the reference takes nothing that the program made.
+program takes (layer-stacked, in the family's groups), from the
+configuration file's sizes alone.  The program and the reference are
+both handed these arrays, so the reference takes nothing that the
+program made.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from chipbench.reference import TARGETS
-
-
-def base_shapes(dims):
-    """{path: shape} of the base weights of a GPT-style model."""
-    d, L, ff = dims["d_model"], dims["layers"], dims["d_ff"]
-    s = {"embed/tok": (dims["vocab"], d), "embed/pos": (dims["positions"], d),
-         "final_norm/scale": (d,), "final_norm/bias": (d,),
-         "dec/norm1/scale": (L, d), "dec/norm1/bias": (L, d),
-         "dec/norm2/scale": (L, d), "dec/norm2/bias": (L, d),
-         "dec/wq": (L, d, d), "dec/wk": (L, d, d), "dec/wv": (L, d, d),
-         "dec/wo": (L, d, d), "dec/w_in": (L, d, ff), "dec/w_out": (L, ff, d),
-         "dec/b_in": (L, ff), "dec/b_out": (L, d)}
-    if dims["qkv_bias"]:
-        s.update({"dec/bq": (L, d), "dec/bk": (L, d), "dec/bv": (L, d)})
-    if dims["out_bias"]:
-        s["dec/bo"] = (L, d)
-    return s
+from chipbench.reference import layer_groups, static_ranks
 
 
 def _nest(flat):
@@ -42,76 +27,77 @@ def _nest(flat):
     return out
 
 
-def _draw(key, path, shape, dtype):
-    leaf = path.rsplit("/", 1)[-1]
-    if leaf == "scale":                       # layer-norm gains
-        return 1.0 + 0.1 * jax.random.normal(key, shape, dtype)
-    if leaf in ("tok", "pos"):
-        return 0.02 * jax.random.normal(key, shape, dtype)
-    if leaf.startswith("b") or path.endswith("/bias"):
-        return 0.02 * jax.random.normal(key, shape, dtype)
-    fan_in = shape[-2]
-    return jax.random.normal(key, shape, dtype) * fan_in ** -0.5
-
-
 def make_base(dims, key, dtype=jnp.float32):
-    shapes = base_shapes(dims)
+    fam = dims["family"]
+    shapes = fam.base_shapes(dims)
     paths = sorted(shapes)
 
     def build(k):
         ks = jax.random.split(k, len(paths))
-        return _nest({p: _draw(kk, p, shapes[p], dtype)
+        return _nest({p: fam.draw(kk, p, shapes[p], dtype)
                       for p, kk in zip(paths, ks)})
 
     return jax.jit(build)(key)
+
+
+def adapter_targets(dims):
+    """[(group, target, its layers, d_in, d_out)] in the program's layout,
+    in the order the adapter makers fold their keys."""
+    return [(g, t, ls, *io) for g, ls in layer_groups(dims).items()
+            for t, io in dims["layer"][ls[0]]["targets"].items()]
 
 
 def make_train_adapters(dims, lora, n_clients, key, dtype=jnp.float32):
     """(client_adapters, server_adapters) in the program's layout at the
     start of training: A ~ N(0, 1/r), B = 0, per client and for the
     server."""
-    d, L, r = dims["d_model"], dims["layers"], lora["r_others"]
+    r = lora["r_others"]
 
     def build(k):
         kc, ks = jax.random.split(k)
         cad, sad = {}, {}
-        for i, t in enumerate(TARGETS):
-            cad[t] = {"A": jax.random.normal(jax.random.fold_in(kc, i),
-                                             (L, n_clients, d, r), dtype)
-                      * r ** -0.5,
-                      "B": jnp.zeros((L, n_clients, r, d), dtype)}
-            sad[t] = {"A": jax.random.normal(jax.random.fold_in(ks, i),
-                                             (L, d, r), dtype) * r ** -0.5,
-                      "B": jnp.zeros((L, r, d), dtype)}
-        return {"dec": cad}, {"dec": sad}
+        for i, (g, t, ls, din, dout) in enumerate(adapter_targets(dims)):
+            n = len(ls)
+            cad.setdefault(g, {})[t] = {
+                "A": jax.random.normal(jax.random.fold_in(kc, i),
+                                       (n, n_clients, din, r), dtype)
+                * r ** -0.5,
+                "B": jnp.zeros((n, n_clients, r, dout), dtype)}
+            sad.setdefault(g, {})[t] = {
+                "A": jax.random.normal(jax.random.fold_in(ks, i),
+                                       (n, din, r), dtype) * r ** -0.5,
+                "B": jnp.zeros((n, r, dout), dtype)}
+        return cad, sad
 
     return jax.jit(build)(key)
 
 
 def make_pool(dims, lora, n_adapters, key, dtype=jnp.float32):
     """A serving pool of trained-looking adapters: every A and B drawn,
-    rank r_cut (masked slots) with scale alpha / r_cut on the two layers
+    rank r_cut (masked slots) with scale alpha / r_cut on the layers
     around the configured cut, rank r_others elsewhere, as
     `merge_adapters` leaves a client's personalised adapter."""
-    d, L, r = dims["d_model"], dims["layers"], lora["r_others"]
-    cut = lora["cut_layer"]
-    ranks = [lora["r_cut"] if l in (cut - 1, cut) else r for l in range(L)]
-    rmask = (jnp.arange(r)[None, :] < jnp.asarray(ranks)[:, None]).astype(
-        dtype)                                                  # (L, r)
-    scale = jnp.broadcast_to(
-        (lora["alpha"] / jnp.asarray(ranks, jnp.float32))[:, None],
-        (L, n_adapters))
+    r = lora["r_others"]
+    ranks = np.asarray(static_ranks(dims, lora))
 
     def build(k):
         pool = {}
-        for i, t in enumerate(TARGETS):
+        for i, (g, t, ls, din, dout) in enumerate(adapter_targets(dims)):
+            rk = ranks[ls]
+            rmask = (jnp.arange(r)[None, :] < jnp.asarray(rk)[:, None]
+                     ).astype(dtype)                           # (L_g, r)
+            n = len(ls)
             ka, kb = jax.random.split(jax.random.fold_in(k, i))
-            a = jax.random.normal(ka, (L, n_adapters, d, r), dtype) * r ** -0.5
-            b = 0.02 * jax.random.normal(kb, (L, n_adapters, r, d), dtype)
-            pool[t] = {"A": a * rmask[:, None, None, :],
-                       "B": b * rmask[:, None, :, None],
-                       "scale": scale}
-        return {"dec": pool}
+            a = jax.random.normal(ka, (n, n_adapters, din, r), dtype) \
+                * r ** -0.5
+            b = 0.02 * jax.random.normal(kb, (n, n_adapters, r, dout), dtype)
+            pool.setdefault(g, {})[t] = {
+                "A": a * rmask[:, None, None, :],
+                "B": b * rmask[:, None, :, None],
+                "scale": jnp.broadcast_to(
+                    (lora["alpha"] / jnp.asarray(rk, jnp.float32))[:, None],
+                    (n, n_adapters))}
+        return pool
 
     return jax.jit(build)(key)
 
