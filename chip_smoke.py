@@ -123,21 +123,25 @@ def phase_kernels(summary):
         tag = jnp.dtype(dtype).name
         print(f"-- {tag}", flush=True)
 
-        # flash attention, causal, forward + backward
+        # flash attention, causal, forward + backward; global and with
+        # gpt-neo's window of 256 (the diagonal strips of both)
         q, k, v, g = (rand((2, s, h, hd), dtype) for _ in range(4))
 
-        def fa_vjp(attn):
+        def fa_vjp(attn, window):
             def f(q_, k_, v_, g_):
-                out, vjp = jax.vjp(lambda *t: attn(*t, causal=True),
-                                   q_, k_, v_)
+                out, vjp = jax.vjp(
+                    lambda *t: attn(*t, causal=True, window=window),
+                    q_, k_, v_)
                 return (out,) + vjp(g_)
             return f
 
-        got = kernel(("flash_attention_pallas", "flash_attention_bwd_pallas"),
-                     fa_vjp(fa_ops.flash_attention), q, k, v, g)
-        want = oracle(fa_vjp(fa_ref.attention), q, k, v, g)
-        for nm, a, b in zip(("out", "dq", "dk", "dv"), got, want):
-            _close(f"flash {nm}", a, b, tol)
+        for window in (0, 256):
+            got = kernel(("flash_attention_pallas",
+                          "flash_attention_bwd_pallas"),
+                         fa_vjp(fa_ops.flash_attention, window), q, k, v, g)
+            want = oracle(fa_vjp(fa_ref.attention, window), q, k, v, g)
+            for nm, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+                _close(f"flash w{window} {nm}", a, b, tol)
 
         # fused LoRA projection, forward + backward (frozen base)
         x, gy = rand((4 * s, d), dtype), rand((4 * s, d), dtype)

@@ -9,9 +9,27 @@ Forward (DESIGN.md §4): blocked online-softmax over KV tiles.
   the (b*h) grid coordinate maps K/V to head h // group_size, so KV heads
   are never materialized per Q head in HBM.
 
-  Causal skip: KV tiles strictly above the diagonal are skipped via
-  pl.when on the whole tile body (Mosaic executes the grid sequentially
-  per core, so the skip saves real time on TPU).
+  Tile skip: KV tiles strictly above the diagonal, or entirely left of
+  every query's window, are skipped via pl.when on the whole tile body
+  (Mosaic executes the grid sequentially per core, so the skip saves real
+  time on TPU).  It needs more than one tile per row: at S <= 512 the one
+  tile is the diagonal one and the skip never engages.
+
+  Diagonal strips: a causal tile on the diagonal (q_start == k_start,
+  read at run time, so with q_offset 0 that is iq == ik) is walked in
+  static strips of STRIP = 128 rows, the MXU and lane width.  Query strip
+  i takes only the keys its rows can see, [lo_i, 128 (i + 1)), lo_i the
+  window's first key rounded down to 128 (0 without a window), and gives
+  its rows of m, l and acc one online update.  At bq = bk = 512 the
+  strips visit 62.5% of the tile's scores causal and 56.25% with a
+  window of 256 (`visited_share`); the pairs they leave out were masked
+  to exact zeros.  The dK/dV kernel walks the same plan transposed: key
+  strip j takes the query rows that can see it.  The strips apply where
+  the tile is causal, square and at least two strips tall; every other
+  tile (off the diagonal, decode-style suffix queries, non-causal
+  attention, bq < 256) runs the whole-tile body.  Each traced call counts
+  under `flash.lowered.strips` or `flash.lowered.full_tile`
+  (`repro.runtime.spans`).
 
   Besides the output the forward emits the logsumexp residual
   lse = m + log(l), shaped (B*H, S_q, 1) fp32 — everything the backward
@@ -33,18 +51,20 @@ Backward: recompute-free dQ / dK / dV from the saved (out, lse).
                KV gradients are never materialized in HBM (the group
                reduction happens in-grid).
 
-  The same tile-level causal/window skip applies on both sides: a
-  (q-tile, kv-tile) pair participates iff some (q_pos, k_pos) in it is
-  unmasked, which is one predicate shared by all three kernels.
+  The same tile-level causal/window skip and diagonal strips apply on
+  both sides: a (q-tile, kv-tile) pair participates iff some
+  (q_pos, k_pos) in it is unmasked, which is one predicate shared by all
+  three kernels.
 
 q_offset (absolute position of q[0], decode with a KV cache) is a traced
 SMEM scalar, NOT a static arg: decode calls with a different offset every
 step, and a static offset would recompile (and, upstream, grow the
 custom_vjp cache) per step.
 
-Block sizes: bq/bk default 512/512 for long-context prefill — head_dim
-(64..128) keeps tiles at 512*128*4B = 256 KiB, well under VMEM with
-double buffering.
+Block sizes: bq/bk default 512/512 — head_dim (64..128) keeps tiles at
+512*128*4B = 256 KiB, well under VMEM with double buffering.  At the
+training length 512 that is one tile per (row, head), whose causal and
+window saving comes from the diagonal strips alone.
 """
 
 from __future__ import annotations
@@ -57,11 +77,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import mxu
+from repro.runtime import spans
 
 NEG_INF = -1e30
 
 DEFAULT_BQ = 512
 DEFAULT_BK = 512
+STRIP = 128
 
 
 def _tile_live(q_start, k_start, *, causal: bool, window: int,
@@ -81,14 +103,76 @@ def _tile_live(q_start, k_start, *, causal: bool, window: int,
 
 def _pair_mask(q_start, k_start, *, causal: bool, window: int,
                bq: int, bk: int):
-    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    # q_pos - k_pos from a column of rows and a row of keys
+    d = ((q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0))
+         - (k_start + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)))
     mask = jnp.ones((bq, bk), jnp.bool_)
     if causal:
-        mask &= q_pos >= k_pos
+        mask &= d >= 0
     if window > 0:
-        mask &= q_pos - k_pos < window
+        mask &= d < window
     return mask
+
+
+def _strips(causal: bool, window: int, bq: int, bk: int):
+    """The diagonal tile's walk: per query strip, ((row0, rows),
+    (key0, keys)), or None where the tile runs whole.  Query strip i sees
+    keys up to its own last row (causal) and from its first row's window
+    start, rounded down to a strip."""
+    if not causal or bq != bk or bq % STRIP or bq < 2 * STRIP:
+        return None
+    plan = []
+    for r0 in range(0, bq, STRIP):
+        lo = 0 if window <= 0 else max(0, (r0 - window + 1) // STRIP * STRIP)
+        plan.append(((r0, STRIP), (lo, r0 + STRIP - lo)))
+    return tuple(plan)
+
+
+def _strips_by_key(plan):
+    """The same walk transposed: per key strip, ((row0, rows), (key0,
+    keys)) with the query rows of every query strip that visits it."""
+    out = []
+    for k0 in range(0, len(plan) * STRIP, STRIP):
+        rows = [r0 for (r0, _), (lo, n) in plan if lo <= k0 < lo + n]
+        out.append(((rows[0], rows[-1] + STRIP - rows[0]), (k0, STRIP)))
+    return tuple(out)
+
+
+def visited_share(causal: bool, window: int, bq: int, bk: int) -> float:
+    """Share of a diagonal (bq, bk) tile's score elements a call
+    computes: 1.0 where the tile runs whole."""
+    plan = _strips(causal, window, bq, bk)
+    if plan is None:
+        return 1.0
+    return sum(r * k for (_, r), (_, k) in plan) / (bq * bk)
+
+
+def _count_path(plan):
+    spans.count("flash.lowered.full_tile" if plan is None
+                else "flash.lowered.strips")
+
+
+def _walk(q_start, k_start, plan, body, *, causal: bool, window: int,
+          bq: int, bk: int):
+    """Run `body(rows, keys, q_pos, k_pos)` over the live whole tile, or
+    over the strips of `plan` where the tile is on the diagonal; q_pos and
+    k_pos are the positions of the first row and key.  On the diagonal
+    only their difference matters, so the strips pass static ones."""
+    def whole():
+        body(pl.ds(0, bq), pl.ds(0, bk), q_start, k_start)
+
+    run = _tile_live(q_start, k_start, causal=causal, window=window,
+                     bq=bq, bk=bk)
+    if plan is None:
+        pl.when(run)(whole)
+        return
+    diag = q_start == k_start
+    pl.when(jnp.logical_and(run, jnp.logical_not(diag)))(whole)
+
+    @pl.when(diag)
+    def _strip_walk():
+        for (r0, nr), (k0, nk) in plan:
+            body(pl.ds(r0, nr), pl.ds(k0, nk), r0, k0)
 
 
 # ---------------------------------------------------------------------------
@@ -97,7 +181,7 @@ def _pair_mask(q_start, k_start, *, causal: bool, window: int,
 
 def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_ref, l_ref, acc_ref, *, scale: float, causal: bool,
-                window: int, bq: int, bk: int, n_kv: int):
+                window: int, bq: int, bk: int, n_kv: int, plan):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
 
@@ -107,34 +191,32 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_start = iq * bq + qoff_ref[0]
-    k_start = ik * bk
-
-    run = _tile_live(q_start, k_start, causal=causal, window=window,
-                     bq=bq, bk=bk)
-
-    @pl.when(run)
-    def _body():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
+    def body(rows, keys, q_pos, k_pos):
+        """One online-softmax update of the running rows `rows` by the
+        keys `keys` of this tile."""
+        q = q_ref[0, rows].astype(jnp.float32) * scale
+        k = k_ref[0, 0, keys].astype(jnp.float32)
         prec = mxu.precision(q_ref.dtype)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 precision=prec,
                                 preferred_element_type=jnp.float32)
-        mask = _pair_mask(q_start, k_start, causal=causal, window=window,
-                          bq=bq, bk=bk)
+        mask = _pair_mask(q_pos, k_pos, causal=causal, window=window,
+                          bq=rows.size, bk=keys.size)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
+        m_prev = m_ref[rows]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p.astype(v_ref.dtype), v_ref[0, 0], precision=prec,
+        l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, -1, keepdims=True)
+        acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot(
+            p.astype(v_ref.dtype), v_ref[0, 0, keys], precision=prec,
             preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        m_ref[rows] = m_new
+
+    _walk(iq * bq + qoff_ref[0], ik * bk, plan, body, causal=causal,
+          window=window, bq=bq, bk=bk)
 
     @pl.when(ik == n_kv - 1)
     def _finalize():
@@ -169,6 +251,8 @@ def flash_attention_pallas(q, k, v, q_offset=0, *, causal: bool = True,
     if sq % bq or sk % bk:
         raise ValueError(f"seq ({sq},{sk}) not divisible by ({bq},{bk})")
     n_kv = sk // bk
+    plan = _strips(causal, window, bq, bk)
+    _count_path(plan)
 
     # layout: (B*H, S, hd) for Q/O; K/V stay (B, KVH, S, hd), GQA via index_map
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, hd)
@@ -183,7 +267,7 @@ def flash_attention_pallas(q, k, v, q_offset=0, *, causal: bool = True,
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          window=window, bq=bq, bk=bk, n_kv=n_kv),
+                          window=window, bq=bq, bk=bk, n_kv=n_kv, plan=plan),
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                # q_offset
@@ -216,31 +300,32 @@ def flash_attention_pallas(q, k, v, q_offset=0, *, causal: bool = True,
 # backward
 
 
-def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-              q_start, k_start, *, scale, causal, window, bq, bk):
-    """Shared tile math: probabilities p and score gradient ds (both
-    (bq, bk) fp32, scale folded into ds)."""
-    q = q_ref[0].astype(jnp.float32) * scale
-    k = k_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, keys,
+              q_pos, k_pos, *, scale, causal, window):
+    """Shared tile math over the query rows `rows` and keys `keys`:
+    probabilities p and score gradient ds (both (rows, keys) fp32, scale
+    folded into ds), and dO's rows."""
+    q = q_ref[0, rows].astype(jnp.float32) * scale
+    k = k_ref[0, 0, keys].astype(jnp.float32)
+    v = v_ref[0, 0, keys].astype(jnp.float32)
+    do = do_ref[0, rows].astype(jnp.float32)
     prec = mxu.precision(q_ref.dtype)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=prec,
                             preferred_element_type=jnp.float32)
-    mask = _pair_mask(q_start, k_start, causal=causal, window=window,
-                      bq=bq, bk=bk)
+    mask = _pair_mask(q_pos, k_pos, causal=causal, window=window,
+                      bq=rows.size, bk=keys.size)
     s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse_ref[0])                          # (bq, bk)
+    p = jnp.exp(s - lse_ref[0, rows])                    # (rows, keys)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              precision=prec,
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0]) * scale
+    ds = p * (dp - delta_ref[0, rows]) * scale
     return p, ds, do
 
 
 def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_acc, *, scale: float, causal: bool,
-                   window: int, bq: int, bk: int, n_kv: int):
+                   window: int, bq: int, bk: int, n_kv: int, plan):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
 
@@ -248,19 +333,17 @@ def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    q_start = iq * bq + qoff_ref[0]
-    k_start = ik * bk
-    run = _tile_live(q_start, k_start, causal=causal, window=window,
-                     bq=bq, bk=bk)
-
-    @pl.when(run)
-    def _body():
+    def body(rows, keys, q_pos, k_pos):
         _, ds, _ = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                             delta_ref, q_start, k_start, scale=scale,
-                             causal=causal, window=window, bq=bq, bk=bk)
-        dq_acc[...] += jax.lax.dot(ds, k_ref[0, 0].astype(jnp.float32),
-                                   precision=mxu.precision(q_ref.dtype),
-                                   preferred_element_type=jnp.float32)
+                             delta_ref, rows, keys, q_pos, k_pos,
+                             scale=scale, causal=causal, window=window)
+        dq_acc[rows] += jax.lax.dot(
+            ds, k_ref[0, 0, keys].astype(jnp.float32),
+            precision=mxu.precision(q_ref.dtype),
+            preferred_element_type=jnp.float32)
+
+    _walk(iq * bq + qoff_ref[0], ik * bk, plan, body, causal=causal,
+          window=window, bq=bq, bk=bk)
 
     @pl.when(ik == n_kv - 1)
     def _finalize():
@@ -270,7 +353,7 @@ def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                     scale: float, causal: bool, window: int, bq: int,
-                    bk: int, n_q: int, group: int):
+                    bk: int, n_q: int, group: int, plan):
     ik = pl.program_id(1)
     g = pl.program_id(2)
     iq = pl.program_id(3)
@@ -280,24 +363,21 @@ def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_start = iq * bq + qoff_ref[0]
-    k_start = ik * bk
-    run = _tile_live(q_start, k_start, causal=causal, window=window,
-                     bq=bq, bk=bk)
-
-    @pl.when(run)
-    def _body():
+    def body(rows, keys, q_pos, k_pos):
         p, ds, do = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                              delta_ref, q_start, k_start, scale=scale,
-                              causal=causal, window=window, bq=bq, bk=bk)
+                              delta_ref, rows, keys, q_pos, k_pos,
+                              scale=scale, causal=causal, window=window)
         # contract over the q rows: p^T dO and ds^T q, no explicit transpose
         prec = mxu.precision(q_ref.dtype)
-        dv_acc[...] += jax.lax.dot_general(
+        dv_acc[keys] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), precision=prec,
             preferred_element_type=jnp.float32)
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
+        dk_acc[keys] += jax.lax.dot_general(
+            ds, q_ref[0, rows].astype(jnp.float32), (((0,), (0,)), ((), ())),
             precision=prec, preferred_element_type=jnp.float32)
+
+    _walk(iq * bq + qoff_ref[0], ik * bk, plan, body, causal=causal,
+          window=window, bq=bq, bk=bk)
 
     @pl.when(jnp.logical_and(g == group - 1, iq == n_q - 1))
     def _finalize():
@@ -328,6 +408,8 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, q_offset=0, *,
     if sq % bq or sk % bk:
         raise ValueError(f"seq ({sq},{sk}) not divisible by ({bq},{bk})")
     n_q, n_kv = sq // bq, sk // bk
+    plan = _strips(causal, window, bq, bk)
+    _count_path(plan)
 
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, hd)
     kt = k.transpose(0, 2, 1, 3)
@@ -347,7 +429,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, q_offset=0, *,
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          window=window, bq=bq, bk=bk, n_kv=n_kv),
+                          window=window, bq=bq, bk=bk, n_kv=n_kv, plan=plan),
         grid=(b * h, n_q, n_kv),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                # q_offset
@@ -385,7 +467,9 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, q_offset=0, *,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           window=window, bq=bq, bk=bk, n_q=n_q,
-                          group=group),
+                          group=group,
+                          plan=None if plan is None
+                          else _strips_by_key(plan)),
         grid=(b * kvh, n_kv, group, n_q),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                # q_offset

@@ -135,6 +135,9 @@ def _check_flash_parity(b, sq, sk, h, kvh, hd, window, off, dtype, seed=0):
     (1, 128, 8, 8, 32, 64),     # MHA + sliding window
     (2, 128, 4, 1, 32, 0),      # MQA (group == h)
     (1, 96, 4, 2, 64, 32),      # ragged S + window
+    (1, 512, 2, 1, 64, 0),      # the training tile: diagonal strips
+    (1, 512, 2, 2, 64, 256),    # strips with a window of 256
+    (1, 1024, 1, 1, 64, 0),     # strips on the diagonal, whole tile below
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_grad_parity(b, s, h, kvh, hd, window, dtype):

@@ -24,12 +24,14 @@ from hypothesis_compat import given, settings, st   # noqa: E402
 from repro.kernels.decode_attention import ops as dec_ops   # noqa: E402
 from repro.kernels.decode_attention import ref as dec_ref   # noqa: E402
 from repro.kernels.flash_attention import ops as fa_ops     # noqa: E402
+from repro.kernels.flash_attention import kernel as fa_kernel  # noqa: E402
 from repro.kernels.flash_attention import ref as fa_ref     # noqa: E402
 from repro.kernels.lora_matmul import ops as lora_ops       # noqa: E402
 from repro.kernels.lora_matmul import ref as lora_ref       # noqa: E402
 from repro.kernels.lora_matmul.kernel import lora_matmul_pallas  # noqa: E402
 from repro.kernels.ssd_scan import ops as ssd_ops           # noqa: E402
 from repro.kernels.ssd_scan import ref as ssd_ref           # noqa: E402
+from repro.runtime import spans                             # noqa: E402
 
 
 def tol(dtype):
@@ -106,6 +108,9 @@ def test_lora_rank_zero_B_is_identity(r, scale):
     (1, 512, 8, 8, 64, 128),
     (2, 128, 4, 1, 32, 0),
     (1, 256, 8, 4, 128, 64),
+    (1, 512, 2, 1, 64, 0),      # the training tile: diagonal strips
+    (1, 512, 2, 2, 64, 256),    # strips with a window of 256
+    (1, 1024, 1, 1, 64, 0),     # strips on the diagonal, whole tile below
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_sweep(b, s, h, kvh, hd, window, dtype):
@@ -117,6 +122,35 @@ def test_flash_attention_sweep(b, s, h, kvh, hd, window, dtype):
     want = fa_ref.attention(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **tol(dtype))
+
+
+@pytest.mark.parametrize("window,share", [(0, 0.625), (256, 0.5625)])
+def test_flash_strip_plan_and_counters(window, share):
+    """At the training tile (S = bq = bk = 512) the diagonal strips visit
+    5/8 of the scores causal and 9/16 with a window of 256, and lowering
+    that call takes the strip path; a decode-style call with a traced
+    offset and a non-causal call keep the whole tile."""
+    assert fa_kernel.visited_share(True, window, 512, 512) == share
+    assert fa_kernel.visited_share(False, 0, 512, 512) == 1.0
+    assert fa_kernel.visited_share(True, 0, 64, 192) == 1.0
+
+    def lowered(q, kv, *extra, **kw):
+        fa_kernel.flash_attention_pallas.clear_cache()
+        before = spans.counters()
+        f32 = [jax.ShapeDtypeStruct(s, jnp.float32) for s in (q, kv, kv)]
+        fa_kernel.flash_attention_pallas.lower(*f32, *extra, interpret=True,
+                                               **kw)
+        after = spans.counters()
+        return {k: after.get(k, 0) - before.get(k, 0)
+                for k in ("flash.lowered.strips", "flash.lowered.full_tile")}
+
+    strips = {"flash.lowered.strips": 1, "flash.lowered.full_tile": 0}
+    whole = {"flash.lowered.strips": 0, "flash.lowered.full_tile": 1}
+    assert lowered((2, 512, 12, 64), (2, 512, 12, 64),
+                   window=window) == strips
+    assert lowered((1, 64, 4, 32), (1, 192, 2, 32),
+                   jax.ShapeDtypeStruct((), jnp.int32)) == whole
+    assert lowered((1, 512, 2, 64), (1, 512, 2, 64), causal=False) == whole
 
 
 def test_chunked_attention_matches_direct():
