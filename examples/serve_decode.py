@@ -4,8 +4,7 @@
 
 Fine-tunes briefly, extracts the aggregated global adapters (paper b4),
 then runs the serving path: one prefill over the prompt batch and a
-greedy decode loop against the KV cache — the same code path the
-decode_32k/long_500k dry-run cells lower.
+greedy decode loop against the KV cache.
 """
 
 import dataclasses

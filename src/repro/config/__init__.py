@@ -3,7 +3,6 @@ from repro.config.base import (
     ArchConfig,
     DataConfig,
     LoRAConfig,
-    MeshConfig,
     ModelConfig,
     ShapeConfig,
     SplitConfig,
@@ -16,7 +15,6 @@ __all__ = [
     "ArchConfig",
     "DataConfig",
     "LoRAConfig",
-    "MeshConfig",
     "ModelConfig",
     "ShapeConfig",
     "SplitConfig",

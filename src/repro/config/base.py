@@ -5,21 +5,23 @@ Everything a run needs is described by a tree of frozen dataclasses:
   ArchConfig        -- one per architecture (src/repro/configs/<id>.py)
     ModelConfig     -- backbone hyperparameters
     LoRAConfig      -- per-layer rank policy (the paper's C2)
-    SplitConfig     -- cut-layer placement + adaptive policy (C1/C3)
+    SplitConfig     -- cut-layer placement + smashed compressor (C1)
   TrainConfig       -- optimizer / schedule / remat / dtype knobs
   DataConfig        -- dataset + partitioner (C4)
   ShapeConfig       -- one of the assigned (seq_len, global_batch, kind) cells
-  MeshConfig        -- device mesh geometry
+
+How one run is driven (scheduler, C3 controller, simulated clock) is
+repro.core.system.SystemConfig; no setting lives in both places.
 
 Configs are plain data: no jax imports here, so importing a config never
-touches device state (required by the dry-run contract).
+touches device state.
 """
 
 from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Tuple
 
 # ---------------------------------------------------------------------------
 # Model
@@ -81,7 +83,7 @@ class ModelConfig:
     num_encoder_layers: int = 0
     encoder_seq_len: int = 0          # fixed encoder output length (1500 frames)
 
-    # Modality frontend stubs (vlm / audio): input_specs() supplies
+    # Modality frontend stubs (vlm / audio): the batch supplies
     # precomputed patch/frame embeddings of this many prefix positions.
     frontend_prefix_len: int = 0
     frontend_dim: int = 0             # embedding dim supplied by the stub
@@ -230,8 +232,14 @@ class LoRAConfig:
 
 @dataclass(frozen=True)
 class SplitConfig:
+    """What an architecture's registry entry decides: where the cut may
+    go, and the smashed compressor its deployment defaults to.  How one
+    run is driven (scheduler, C3 controller, aggregation) lives in
+    repro.core.system.SystemConfig; a per-run change to a field here is
+    made by replacing the arch (as launch.train.build_system does for
+    --cut and --smashed-compress)."""
+
     cut_layer: int = 2                  # m: number of client-side layers
-    adaptive: bool = True               # paper C3
     gamma: float = 0.5                  # weight-rule control factor
     cut_buckets: Tuple[int, ...] = ()   # allowed cut positions (static set);
                                         # empty -> {1..min(8, M-1)} ∪ {cut_layer}
@@ -243,54 +251,6 @@ class SplitConfig:
     # deployments of the large assigned archs default to int8.
     smashed_compress: str = "none"
     smashed_topk_frac: float = 0.1      # kept fraction for the topk scheme
-    # Round scheduler (repro.core.scheduler): sync (paper Algorithm 1) |
-    # deadline (straggler drop) | local_steps (speed-proportional K_i) |
-    # async (FedBuff-style buffered asynchrony, no barrier).
-    # SystemConfig.scheduler overrides per run.
-    scheduler: str = "sync"
-    max_local_steps: int = 4            # static K cap for local_steps
-    deadline_frac: float = 1.5          # drop threshold for deadline
-    async_buffer_size: int = 2          # async: aggregate every M distinct
-                                        # client completions (clamped to N)
-    staleness_power: float = 0.5        # async: (1+staleness)^-p discount
-    # Overlapped communication (simulated clock only — training numerics
-    # are identical): pipeline the per-step phases (client compute -> f2
-    # uplink -> server compute -> f4 downlink -> adapter sync) double-
-    # buffered, one outstanding transfer per direction, so uplink of
-    # step k hides behind compute of k+1.  False = the legacy serial
-    # clock (phases charged back to back).
-    overlap_comm: bool = False
-    # C3 controller: "accuracy" = the paper's accuracy-only cut rule;
-    # "co" = the phase-time co-controller — per client, pick the (cut
-    # bucket, rank-at-cut bucket, smashed compressor) triple minimizing
-    # the PREDICTED pipelined makespan (SpeedModel.phase_times over
-    # comm.py bytes), with accuracy gating direction via the dead-band
-    # (repro.core.adaptive.co_adjust).
-    controller: str = "accuracy"
-    rank_buckets: Tuple[int, ...] = ()       # rank-at-cut search set;
-                                             # empty -> (lora.r_cut,)
-    compressor_buckets: Tuple[str, ...] = () # smashed-compressor search
-                                             # set; empty ->
-                                             # (smashed_compress,)
-    acc_dead_band: float = 0.002             # accuracy dead-band half-width
-    min_gain: float = 0.05                   # relative predicted-makespan
-                                             # improvement required to move
-                                             # (co_adjust hysteresis)
-    continuous_topk: bool = False            # co: tune the topk keep
-                                             # fraction continuously
-                                             # (state["topk_frac"]);
-                                             # needs "topk" in the
-                                             # compressor buckets
-    # Hierarchical (two-tier) aggregation: clients FedAvg within each of
-    # edge_groups edge aggregators, then the edges FedAvg to the server.
-    # 1 = flat single-tier (the paper path, bitwise).  The edge->server
-    # hop is priced by SpeedModel.server_ingest_bw / edge_bw.
-    edge_groups: int = 1
-    # Down-weight each client's per-inner-step gradient into the shared
-    # server adapters by 1/K_i under local_steps/async so multi-step
-    # clients do not over-train the server side.  K == 1 is bitwise
-    # either way (rounds.make_train_step).
-    server_step_norm: bool = True
 
     def buckets(self, num_layers: int) -> Tuple[int, ...]:
         if self.cut_buckets:
@@ -303,7 +263,7 @@ class SplitConfig:
 
 
 # ---------------------------------------------------------------------------
-# Training / data / shapes / mesh
+# Training / data / shapes
 
 
 @dataclass(frozen=True)
@@ -365,19 +325,6 @@ SHAPES: Dict[str, ShapeConfig] = {
     "decode_32k": ShapeConfig("decode_32k", 32768, 128, "decode"),
     "long_500k": ShapeConfig("long_500k", 524288, 1, "decode"),
 }
-
-
-@dataclass(frozen=True)
-class MeshConfig:
-    shape: Tuple[int, ...] = (16, 16)
-    axes: Tuple[str, ...] = ("data", "model")
-
-    @property
-    def num_devices(self) -> int:
-        n = 1
-        for s in self.shape:
-            n *= s
-        return n
 
 
 # ---------------------------------------------------------------------------

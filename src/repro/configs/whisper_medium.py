@@ -3,8 +3,8 @@
 [audio] 24L d_model=1024 16H (GQA kv=16) d_ff=4096 vocab=51865
 [arXiv:2212.04356; unverified]
 
-The conv frontend is a STUB: input_specs() supplies precomputed 1500-frame
-mel-embeddings (30 s at 50 Hz post-conv).  The paper's client/server split
+The conv frontend is a STUB: the batch's "frames" entry carries precomputed
+1500-frame mel-embeddings (30 s at 50 Hz post-conv).  The paper's client/server split
 maps onto an encoder-side cut — see DESIGN.md §6.
 """
 

@@ -4,7 +4,7 @@ client does before the FedAvg barrier.
 The round *engine* (rounds.make_train_step) is one jitted executable whose
 behaviour is controlled by data — survivor masks, per-client step budgets.
 A `RoundScheduler` is the host-side policy that produces that data each
-round, plus the simulated wall-clock accounting the benchmarks report:
+round, plus the simulated wall-clock accounting the round records carry:
 
   sync         paper Algorithm 1: every client runs exactly one step and
                the round barrier waits for the slowest client.  Default;
@@ -110,7 +110,7 @@ class RoundPlan:
                   a speed model.  Carried through so the round record can
                   report phase-level accounting — e.g. the charged
                   server-phase/adapter-sync time that hierarchical
-                  aggregation reduces (benchmarks/bench_fleet.py).
+                  aggregation reduces (tests/test_population.py).
     """
 
     active: np.ndarray

@@ -12,8 +12,7 @@ and adaptive movement between rounds — runs in one compiled executable.
 
 `smashed_constraint` marks the activation resharding boundary at the cut:
 on a mesh this is where the paper's "smashed data transmission" (f2/f4)
-bytes cross; XLA lowers the layout change to real collectives, which the
-roofline harness measures.
+bytes cross; XLA lowers the layout change to real collectives.
 """
 
 from __future__ import annotations

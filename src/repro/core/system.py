@@ -13,7 +13,7 @@ The round loop itself is split engine/policy:
     (speed-proportional K_i per client), or async (FedBuff-style
     buffered asynchrony).  The scheduler also owns the simulated
     wall-clock accounting (`sim_time` / cumulative `sim_clock` in the
-    round records) that the benchmarks compare.
+    round records).
 
 C3 is likewise split engine/policy.  The round epilogue (`_adjust_c3`)
 runs one of two host-side controllers: `accuracy` (the paper's rule —
@@ -48,13 +48,13 @@ at the current clock with its next batch index.
 
 Everything device-side lives in rounds.py; this class only moves numpy
 batches in and metrics out, so it works identically on CPU (paper-scale
-experiments) and on a mesh (dry-run / production).
+experiments) and on a mesh (the client-axis-sharded engine).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -83,34 +83,38 @@ from repro.runtime.straggler import SpeedModel
 
 @dataclasses.dataclass
 class SystemConfig:
+    """How one run is driven: scheduler, C3 controller, aggregation,
+    simulated clock, checkpoints.  What an architecture's registry entry
+    decides — the cut placement and the smashed compressor
+    (config.SplitConfig), the population (config.DataConfig) — lives in
+    the ArchConfig; no field name is in both."""
+
     num_samples: int = 2000
     eval_samples: int = 256
     adjust_every: int = 1          # C3 cadence (rounds)
     agg_every: int = 1             # FedAvg cadence (rounds)
     compress: str = "none"         # adapter channel: none | topk | int8
     topk_frac: float = 0.05
-    smashed_compress: Optional[str] = None   # f2/f4 channel: none | int8 |
-                                             # fp8 | topk; None -> arch.split
-    smashed_topk_frac: Optional[float] = None
     smashed_ef: Optional[bool] = None  # EF residual for smashed topk;
                                        # None -> on iff compressor is topk
-    scheduler: Optional[str] = None    # sync | deadline | local_steps |
-                                       # async; None -> arch.split.
-                                       # scheduler (straggler_sim promotes
-                                       # sync -> deadline, the legacy
-                                       # spelling)
-    max_local_steps: Optional[int] = None    # None -> arch.split
+    # Round scheduler (repro.core.scheduler): sync (paper Algorithm 1) |
+    # deadline (straggler drop) | local_steps (speed-proportional K_i) |
+    # async (FedBuff-style buffered asynchrony, no barrier).
+    scheduler: Optional[str] = None    # None -> sync, or deadline under
+                                       # straggler_sim (legacy spelling)
+    max_local_steps: int = 4           # static K cap for local_steps
     straggler_sim: bool = False        # attach a SpeedModel
-    deadline_frac: Optional[float] = None    # None -> arch.split
-    buffer_size: Optional[int] = None  # async: aggregate every M distinct
-                                       # client completions; None ->
-                                       # arch.split (clamped to N)
-    staleness_power: Optional[float] = None  # async: (1+s)^-p discount;
-                                             # None -> arch.split
-    overlap_comm: Optional[bool] = None  # pipeline the comm phases so
-                                         # uplink of step k overlaps
-                                         # compute of k+1; None ->
-                                         # arch.split.overlap_comm
+    deadline_frac: float = 1.5         # drop threshold for deadline
+    buffer_size: int = 2               # async: aggregate every M distinct
+                                       # client completions (clamped to N)
+    staleness_power: float = 0.5       # async: (1+staleness)^-p discount
+    # Overlapped communication (simulated clock only — training numerics
+    # are identical): pipeline the per-step phases (client compute -> f2
+    # uplink -> server compute -> f4 downlink -> adapter sync) double-
+    # buffered, one outstanding transfer per direction, so uplink of
+    # step k hides behind compute of k+1.  False = the serial clock
+    # (phases charged back to back).
+    overlap_comm: bool = False
     speed_sigma: Optional[float] = None      # SpeedModel overrides (None
     bw_sigma: Optional[float] = None         # -> SpeedModel defaults);
     jitter_sigma: Optional[float] = None     # 0s = deterministic fleet
@@ -129,30 +133,35 @@ class SystemConfig:
                                               # aggregation shortens)
     edge_bw: Optional[float] = None           # edge->server link (B/s)
                                               # under edge_groups > 1
-    population: Optional[int] = None   # fleet-scale population; None ->
-                                       # arch.data.population; 0 = fleet
-                                       # mode (clients ARE the population)
-    edge_groups: Optional[int] = None  # two-tier aggregation groups;
-                                       # None -> arch.split.edge_groups
-                                       # (1 = flat, bitwise)
-    server_step_norm: Optional[bool] = None  # 1/K_i server-gradient
-                                             # normalization; None ->
-                                             # arch.split.server_step_norm
+    # Hierarchical (two-tier) aggregation: clients FedAvg within each of
+    # edge_groups edge aggregators, then the edges FedAvg to the server.
+    # 1 = flat single-tier (the paper path, bitwise).
+    edge_groups: int = 1
+    # Down-weight each client's per-inner-step gradient into the shared
+    # server adapters by 1/K_i under local_steps/async so multi-step
+    # clients do not over-train the server side.  K == 1 is bitwise
+    # either way (rounds.make_train_step).
+    server_step_norm: bool = True
     checkpoint_dir: Optional[str] = None
     checkpoint_every: int = 0
     keep_checkpoints: int = 3
-    adaptive: Optional[bool] = None   # None -> arch.split.adaptive
-    controller: Optional[str] = None  # C3 controller: accuracy | co;
-                                      # None -> arch.split.controller
-    rank_buckets: Optional[tuple] = None        # co: rank-at-cut search
-                                                # set; None -> arch.split
-                                                # (then (lora.r_cut,))
-    compressor_buckets: Optional[tuple] = None  # co: compressor search
-                                                # set; None -> arch.split
-                                                # (then the configured
-                                                # smashed_compress)
-    acc_dead_band: Optional[float] = None  # None -> arch.split
-    min_gain: Optional[float] = None       # None -> arch.split
+    adaptive: bool = True              # paper C3: move cuts and weights
+    # C3 controller: "accuracy" = the paper's accuracy-only cut rule;
+    # "co" = the phase-time co-controller — per client, pick the (cut
+    # bucket, rank-at-cut bucket, smashed compressor) triple minimizing
+    # the PREDICTED pipelined makespan (SpeedModel.phase_times over
+    # comm.py bytes), with accuracy gating direction via the dead-band
+    # (repro.core.adaptive.co_adjust).
+    controller: str = "accuracy"
+    rank_buckets: Tuple[int, ...] = ()        # co: rank-at-cut search
+                                              # set; empty -> (lora.r_cut,)
+    compressor_buckets: Tuple[str, ...] = ()  # co: compressor search
+                                              # set; empty -> the arch's
+                                              # smashed_compress
+    acc_dead_band: float = 0.002       # accuracy dead-band half-width
+    min_gain: float = 0.05             # relative predicted-makespan
+                                       # improvement required to move
+                                       # (co_adjust hysteresis)
     trace: Optional[str] = None        # replay a recorded heterogeneity
                                        # trace file (runtime/traces.py
                                        # JSON format); implies a
@@ -178,11 +187,10 @@ class SystemConfig:
                                         # per-phase factors to this path
                                         # as FileTrace JSON when run()
                                         # returns
-    continuous_topk: Optional[bool] = None  # co: search the topk keep
-                                            # fraction continuously
-                                            # (state["topk_frac"]);
-                                            # None -> arch.split
-                                            # .continuous_topk
+    continuous_topk: bool = False      # co: search the topk keep
+                                       # fraction continuously
+                                       # (state["topk_frac"]); needs
+                                       # "topk" in the compressor buckets
 
 
 class SplitFTSystem:
@@ -195,9 +203,7 @@ class SplitFTSystem:
         self.seed = seed
         n = arch.data.num_clients
         self.pool = ClientPool(n)
-        self.population = (arch.data.population
-                           if self.sys.population is None
-                           else self.sys.population) or 0
+        self.population = arch.data.population
         if 0 < self.population < n:
             raise ValueError(
                 f"population={self.population} must be >= the cohort "
@@ -255,34 +261,18 @@ class SplitFTSystem:
         # ---- round scheduler (policy) + straggler simulation ----
         sched_name = self.sys.scheduler
         if sched_name is None:
-            sched_name = arch.split.scheduler
-            if sched_name == "sync" and self.sys.straggler_sim:
-                sched_name = "deadline"   # legacy: straggler_sim == drop
-        dl_frac = (arch.split.deadline_frac
-                   if self.sys.deadline_frac is None
-                   else self.sys.deadline_frac)
-        k_cap = (arch.split.max_local_steps
-                 if self.sys.max_local_steps is None
-                 else self.sys.max_local_steps)
-        buf = (arch.split.async_buffer_size
-               if self.sys.buffer_size is None else self.sys.buffer_size)
-        buf = max(1, min(buf, n))      # can never exceed distinct clients
-        spow = (arch.split.staleness_power
-                if self.sys.staleness_power is None
-                else self.sys.staleness_power)
-        self.overlap_comm = (arch.split.overlap_comm
-                             if self.sys.overlap_comm is None
-                             else self.sys.overlap_comm)
-        self.controller = (arch.split.controller
-                           if self.sys.controller is None
-                           else self.sys.controller)
-        if self.controller not in ("accuracy", "co"):
+            # legacy: straggler_sim == drop
+            sched_name = "deadline" if self.sys.straggler_sim else "sync"
+        # can never exceed distinct clients
+        buf = max(1, min(self.sys.buffer_size, n))
+        if self.sys.controller not in ("accuracy", "co"):
             raise ValueError(f"unknown C3 controller "
-                             f"{self.controller!r}; known: accuracy, co")
+                             f"{self.sys.controller!r}; known: accuracy, co")
         self.scheduler = scheduler_lib.make_scheduler(
-            sched_name, deadline_frac=dl_frac, max_local_steps=k_cap,
-            buffer_size=buf, staleness_power=spow,
-            overlap_comm=self.overlap_comm)
+            sched_name, deadline_frac=self.sys.deadline_frac,
+            max_local_steps=self.sys.max_local_steps,
+            buffer_size=buf, staleness_power=self.sys.staleness_power,
+            overlap_comm=self.sys.overlap_comm)
         speed_kw = {k: getattr(self.sys, k)
                     for k in ("speed_sigma", "bw_sigma", "jitter_sigma",
                               "bw_mean", "server_flops_per_s",
@@ -297,7 +287,7 @@ class SplitFTSystem:
         self.speed = (SpeedModel(n, seed=seed, **speed_kw)
                       if (self.sys.straggler_sim
                           or self.scheduler.needs_speed
-                          or self.controller == "co"
+                          or self.sys.controller == "co"
                           or self.sys.trace or self.sys.trace_gen)
                       else None)
         if self.sys.trace:
@@ -368,12 +358,8 @@ class SplitFTSystem:
                                            num_clients=n)
             if self.sys.compress == "topk":
                 self.state = rounds.with_error_feedback(self.state)
-        self.smashed_compress = (arch.split.smashed_compress
-                                 if self.sys.smashed_compress is None
-                                 else self.sys.smashed_compress)
-        self.smashed_topk_frac = (arch.split.smashed_topk_frac
-                                  if self.sys.smashed_topk_frac is None
-                                  else self.sys.smashed_topk_frac)
+        self.smashed_compress = arch.split.smashed_compress
+        self.smashed_topk_frac = arch.split.smashed_topk_frac
         use_smashed_ef = (self.smashed_compress == "topk"
                           if self.sys.smashed_ef is None
                           else self.sys.smashed_ef)
@@ -387,23 +373,14 @@ class SplitFTSystem:
                 self.state = rounds.with_smashed_ef(self.state, self.model)
 
         # ---- co-controller search space (cut x rank x compressor) ----
-        self.acc_dead_band = (arch.split.acc_dead_band
-                              if self.sys.acc_dead_band is None
-                              else self.sys.acc_dead_band)
-        self.min_gain = (arch.split.min_gain if self.sys.min_gain is None
-                         else self.sys.min_gain)
-        rb = (self.sys.rank_buckets if self.sys.rank_buckets is not None
-              else arch.split.rank_buckets) or (arch.lora.r_cut,)
+        rb = self.sys.rank_buckets or (arch.lora.r_cut,)
         self.rank_buckets = tuple(sorted({int(r) for r in rb}))
         if any(r < 1 or r > arch.lora.r_others for r in self.rank_buckets):
             raise ValueError(
                 f"rank_buckets {self.rank_buckets} must lie in "
                 f"[1, r_others={arch.lora.r_others}] (adapters are "
                 "allocated at r_others; ranks are masks, not shapes)")
-        cbk = (self.sys.compressor_buckets
-               if self.sys.compressor_buckets is not None
-               else arch.split.compressor_buckets) \
-            or (self.smashed_compress,)
+        cbk = self.sys.compressor_buckets or (self.smashed_compress,)
         # bucket index order == aggressiveness order: weakest compression
         # (most wire bytes) first, so "one step weaker" is index - 1
         self.comp_buckets = tuple(sorted(
@@ -412,14 +389,11 @@ class SplitFTSystem:
                 nm, batch=arch.train.batch_size, seq=arch.train.seq_len,
                 d_model=arch.model.d_model,
                 topk_frac=self.smashed_topk_frac)))
-        self.continuous_topk = (arch.split.continuous_topk
-                                if self.sys.continuous_topk is None
-                                else self.sys.continuous_topk)
-        if self.continuous_topk:
-            if self.controller != "co":
+        if self.sys.continuous_topk:
+            if self.sys.controller != "co":
                 raise ValueError(
                     "continuous_topk is a co-controller search knob; "
-                    f"set controller='co' (got {self.controller!r})")
+                    f"set controller='co' (got {self.sys.controller!r})")
             if "topk" not in self.comp_buckets:
                 raise ValueError(
                     "continuous_topk tunes the topk compressor's keep "
@@ -427,15 +401,10 @@ class SplitFTSystem:
                     f"buckets {self.comp_buckets}")
 
         # ---- hierarchical aggregation + server-step normalization ----
-        self.num_edges = max(1, (arch.split.edge_groups
-                                 if self.sys.edge_groups is None
-                                 else self.sys.edge_groups) or 1)
-        self.server_step_norm = (arch.split.server_step_norm
-                                 if self.sys.server_step_norm is None
-                                 else self.sys.server_step_norm)
+        self.num_edges = max(1, self.sys.edge_groups)
 
         is_async = self.scheduler.name == "async"
-        co = self.controller == "co"
+        co = self.sys.controller == "co"
         if co and use_smashed_ef:
             raise ValueError(
                 "the co-controller's per-client compressor choice does "
@@ -454,7 +423,7 @@ class SplitFTSystem:
                 rank_cut=init_rank if co else None,
                 smashed_choice=init_choice if co else None,
                 topk_frac=(self.smashed_topk_frac
-                           if (co and self.continuous_topk) else None),
+                           if (co and self.sys.continuous_topk) else None),
                 edge_groups=self.num_edges)
         with span("splitft.setup.engine"):
             self.train_step = rounds.make_train_step(
@@ -466,8 +435,9 @@ class SplitFTSystem:
                 compressor_buckets=self.comp_buckets if co else None,
                 max_local_steps=self.scheduler.max_steps,
                 async_buffer=is_async, buffer_size=buf,
-                staleness_power=spow, num_edges=self.num_edges,
-                server_step_norm=self.server_step_norm, jit=jit)
+                staleness_power=self.sys.staleness_power,
+                num_edges=self.num_edges,
+                server_step_norm=self.sys.server_step_norm, jit=jit)
             self.eval_step = rounds.make_eval_step(self.model,
                                                    policy=policy, jit=jit)
 
@@ -481,8 +451,6 @@ class SplitFTSystem:
                                        keep=self.sys.keep_checkpoints)
                      if self.sys.checkpoint_dir else None)
         self.history: List[Dict[str, Any]] = []
-        self._adaptive = (arch.split.adaptive if self.sys.adaptive is None
-                          else self.sys.adaptive)
 
         # ---- fleet-scale population (cohort engine) ----
         if self.population:
@@ -764,7 +732,7 @@ class SplitFTSystem:
             (self._state_frac() if topk_frac is None
              else np.asarray(topk_frac, np.float64)))
         phases = self._round_phases(r, cuts_np, cb, jitter=False)
-        if self.overlap_comm:
+        if self.sys.overlap_comm:
             k = max(2, self.scheduler.max_steps)
             steps = np.full(cuts_np.shape[0], k, np.int64)
             return straggler.pipelined_makespan(phases, steps) / k
@@ -834,8 +802,8 @@ class SplitFTSystem:
             rec["sim_time"] = plan.sim_time
             rec["sim_clock"] = self.sim_clock
         if plan.phases is not None:
-            # (5, N) per-phase durations — bench_fleet compares the
-            # charged server ingest + adapter-sync time flat vs two-tier
+            # (5, N) per-phase durations: the charged server ingest +
+            # adapter-sync time that two-tier aggregation shortens
             rec["phase_times"] = np.asarray(plan.phases).copy()
         # each local step is a full f2/f4 exchange, and a dropped/inactive
         # client (budget 0) transmits nothing; it still receives the b3
@@ -886,7 +854,7 @@ class SplitFTSystem:
         self.c3_weights = adaptive.update_weights(
             accs, self.arch.split.gamma)
         active = self.pool.active.astype(np.float64)
-        if self.controller == "co":
+        if self.sys.controller == "co":
             rank_np, choice_np = self._state_policy()
             frac_np = self._state_frac()
             if frac_np is None:
@@ -897,8 +865,8 @@ class SplitFTSystem:
                     num_compressors=len(self.comp_buckets),
                     price=lambda c, rk, ci: self.predict_round_times(
                         r + 1, c, rk, ci),
-                    active=active, dead_band=self.acc_dead_band,
-                    min_gain=self.min_gain, round_times=times)
+                    active=active, dead_band=self.sys.acc_dead_band,
+                    min_gain=self.sys.min_gain, round_times=times)
             else:
                 new_cuts, new_rank, new_comp, new_frac, pred = \
                     adaptive.co_adjust(
@@ -910,8 +878,8 @@ class SplitFTSystem:
                         price=lambda c, rk, ci, fr:
                             self.predict_round_times(r + 1, c, rk, ci,
                                                      topk_frac=fr),
-                        active=active, dead_band=self.acc_dead_band,
-                        min_gain=self.min_gain, round_times=times,
+                        active=active, dead_band=self.sys.acc_dead_band,
+                        min_gain=self.sys.min_gain, round_times=times,
                         topk_frac=frac_np)
                 self.state["topk_frac"] = jnp.asarray(new_frac,
                                                       jnp.float32)
@@ -932,7 +900,7 @@ class SplitFTSystem:
                       callback: Optional[Callable]):
         """Round epilogue shared by the barrier and async host loops:
         C3 adjustment, history, callback, checkpoint cadence, logging."""
-        if self._adaptive and (r + 1) % self.sys.adjust_every == 0:
+        if self.sys.adaptive and (r + 1) % self.sys.adjust_every == 0:
             with span("splitft.c3", round=r):
                 weights = jnp.asarray(self.combined_weights(), jnp.float32)
                 self._adjust_c3(r, rec, weights, rec.get("round_time_sim"))

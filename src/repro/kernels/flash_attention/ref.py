@@ -58,7 +58,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
     running (max, normalizer, accumulator).
 
     This is the XLA-analyzable stand-in for the Pallas kernel on non-TPU
-    backends: it has the kernel's O(S) memory profile, so the dry-run's
+    backends: it has the kernel's O(S) memory profile, so
     memory_analysis() and cost_analysis() reflect the TPU execution plan
     rather than a materialized S^2 score tensor."""
     b, sq, h, hd = q.shape

@@ -3,8 +3,7 @@
 y = x @ W + scale * (x @ A) @ B
 
 This is the semantics contract for the Pallas kernel; it is also the
-execution path on CPU (tests, paper-scale experiments) and under the
-dry-run lowering.
+execution path on CPU (tests, paper-scale experiments).
 """
 
 from __future__ import annotations

@@ -9,7 +9,7 @@ Two oracles:
   * ssd_sequential — the literal per-timestep recurrence (ground truth).
   * ssd_chunked    — the SSD chunked algorithm (intra-chunk quadratic part
     + inter-chunk state carry), the same math the Pallas kernel implements
-    and the CPU/dry-run execution path.
+    and the CPU execution path.
 
 Shapes: x (B,S,H,P), dt (B,S,H) positive, A (H,) negative,
         Bm/C (B,S,G,N) with G | H.  Returns y (B,S,H,P) and final state

@@ -1,8 +1,9 @@
-"""Production mesh construction.
+"""Host mesh construction.
 
 A function, not a module-level constant: importing this module never
-touches jax device state (required so smoke tests see 1 CPU device while
-the dry-run sees 512 placeholder devices)."""
+touches jax device state, so a process can set its device count (e.g.
+XLA_FLAGS=--xla_force_host_platform_device_count) before the first
+mesh is built."""
 
 from __future__ import annotations
 
@@ -15,13 +16,6 @@ def _auto_mesh(shape, axes, devices=None):
     ``with_sharding_constraint``, which only accepts Auto axes."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
                          devices=devices)
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = one v5e pod; (2,16,16) = two pods, 512 chips."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1):

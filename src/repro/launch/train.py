@@ -65,8 +65,8 @@ def build_parser() -> argparse.ArgumentParser:
                     choices=[None, "sync", "deadline", "local_steps",
                              "async"],
                     help="round scheduler (repro.core.scheduler); "
-                         "default: the arch config's choice "
-                         "(--straggler-sim alone implies deadline)")
+                         "default: sync (--straggler-sim alone implies "
+                         "deadline)")
     ap.add_argument("--max-local-steps", type=int, default=None,
                     help="static K cap for --scheduler local_steps")
     ap.add_argument("--deadline-frac", type=float, default=None,
@@ -81,8 +81,7 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--overlap-comm", action="store_true", default=None,
                     help="pipeline the comm phases on the simulated "
                          "clock: uplink of step k overlaps compute of "
-                         "k+1 (double-buffered); default: the arch "
-                         "config's choice")
+                         "k+1 (double-buffered); default: off")
     ap.add_argument("--no-overlap-comm", dest="overlap_comm",
                     action="store_false")
     ap.add_argument("--controller", default=None,
@@ -92,7 +91,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "co-controller picking each client's (cut, "
                          "rank-at-cut, compressor) triple by predicted "
                          "pipelined makespan under an accuracy "
-                         "dead-band; default: the arch config's choice")
+                         "dead-band; default: accuracy")
     ap.add_argument("--rank-buckets", type=_int_list, default=None,
                     metavar="R1,R2,...",
                     help="--controller co: rank-at-cut search set "
@@ -195,12 +194,18 @@ def build_system(args, *, policy=None):
             partition=args.partition or arch.data.partition,
             alpha=args.alpha if args.alpha is not None else arch.data.alpha,
             num_clients=args.clients or arch.data.num_clients))
-    if args.cut or args.adaptive is not None:
-        arch = arch.replace(split=dataclasses.replace(
-            arch.split,
-            cut_layer=args.cut or arch.split.cut_layer,
-            adaptive=(arch.split.adaptive if args.adaptive is None
-                      else args.adaptive)))
+    if args.cohort_size:
+        arch = arch.replace(data=dataclasses.replace(
+            arch.data, num_clients=args.cohort_size))
+    if args.population is not None:
+        arch = arch.replace(data=dataclasses.replace(
+            arch.data, population=args.population))
+    split_kw = {k: v for k, v in (
+        ("cut_layer", args.cut or None),
+        ("smashed_compress", args.smashed_compress),
+        ("smashed_topk_frac", args.smashed_topk_frac)) if v is not None}
+    if split_kw:
+        arch = arch.replace(split=dataclasses.replace(arch.split, **split_kw))
     if args.r_cut or args.r_others:
         arch = arch.replace(lora=dataclasses.replace(
             arch.lora,
@@ -212,40 +217,21 @@ def build_system(args, *, policy=None):
     if args.lr:
         arch = arch.replace(train=dataclasses.replace(
             arch.train, lr_client=args.lr, lr_server=args.lr))
-    if args.cohort_size:
-        arch = arch.replace(data=dataclasses.replace(
-            arch.data, num_clients=args.cohort_size))
 
     os.makedirs(args.out, exist_ok=True)
+    # every run setting the flags leave unset keeps SystemConfig's default
+    run_kw = {k: getattr(args, k) for k in (
+        "scheduler", "max_local_steps", "deadline_frac", "buffer_size",
+        "staleness_power", "overlap_comm", "adaptive", "controller",
+        "rank_buckets", "compressor_buckets", "acc_dead_band", "min_gain",
+        "continuous_topk", "client_flops_per_s", "jitter_sigma",
+        "time_source", "model_seed", "record_trace", "trace", "trace_gen",
+        "edge_groups") if getattr(args, k) is not None}
     sys_cfg = SystemConfig(
         num_samples=args.samples, compress=args.compress,
-        smashed_compress=args.smashed_compress,
-        smashed_topk_frac=args.smashed_topk_frac,
-        scheduler=args.scheduler,
-        max_local_steps=args.max_local_steps,
-        deadline_frac=args.deadline_frac,
-        buffer_size=args.buffer_size,
-        staleness_power=args.staleness_power,
-        overlap_comm=args.overlap_comm,
-        controller=args.controller,
-        rank_buckets=args.rank_buckets,
-        compressor_buckets=args.compressor_buckets,
-        acc_dead_band=args.acc_dead_band,
-        min_gain=args.min_gain,
-        continuous_topk=args.continuous_topk,
-        straggler_sim=args.straggler_sim,
-        client_flops_per_s=args.client_flops_per_s,
-        jitter_sigma=args.jitter_sigma,
-        time_source=args.time_source,
-        ewma_alpha=args.ewma_alpha,
-        model_seed=args.model_seed,
-        record_trace=args.record_trace,
-        trace=args.trace,
-        trace_gen=args.trace_gen,
-        population=args.population,
-        edge_groups=args.edge_groups,
+        straggler_sim=args.straggler_sim, ewma_alpha=args.ewma_alpha,
         checkpoint_dir=os.path.join(args.out, "ckpt"),
-        checkpoint_every=max(args.rounds // 5, 1))
+        checkpoint_every=max(args.rounds // 5, 1), **run_kw)
     return SplitFTSystem(arch, sys_cfg, policy=policy, seed=args.seed)
 
 

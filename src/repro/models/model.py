@@ -14,7 +14,6 @@ Entry points (all pure functions of pytrees):
   prefill(params, adapters, batch, cache, ...)   -> (logits_last, cache)
   decode_step(params, adapters, tokens, cache,..)-> (logits, cache)
   init_cache(lead, max_len, dtype)               -> cache pytree
-  input_specs(shape, ...)                        -> ShapeDtypeStruct dict
 
 Adapters are optional everywhere (None = no LoRA).  Their tree layout is
 {group: {target: {"A": (Lg,[N,]din,r), "B": (Lg,[N,]r,dout),
@@ -24,14 +23,13 @@ Adapters are optional everywhere (None = no LoRA).  Their tree layout is
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.config import ArchConfig, ModelConfig, ShapeConfig
+from repro.config import ArchConfig, ModelConfig
 from repro.models import common, ssm, transformer
 from repro.models.common import NO_SHARDING, ShardingPolicy, apply_norm
 
@@ -131,10 +129,9 @@ def flat_runs(groups: Sequence[GroupSpec]) -> List[Tuple[str, int, int]]:
 class Model:
     def __init__(self, arch: ArchConfig, *, unroll: bool = False):
         """unroll=True replaces lax.scan over layers with a python loop:
-        identical math, straight-line HLO.  Used by the dry-run so that
-        cost_analysis() counts every layer (XLA reports while-loop bodies
-        once, not x trip-count) — and it is the deployment-realistic
-        compile anyway (XLA optimizes across layer boundaries)."""
+        identical math, straight-line HLO, so cost_analysis() counts
+        every layer (XLA reports while-loop bodies once, not x
+        trip-count)."""
         self.arch = arch
         self.cfg = arch.model
         groups = build_groups(self.cfg)
@@ -644,48 +641,6 @@ class Model:
                     cache[g.name]["xk"] = jnp.zeros(xs, dtype)
                     cache[g.name]["xv"] = jnp.zeros(xs, dtype)
         return cache
-
-    # -- input specs (dry-run) ---------------------------------------------------
-
-    def input_specs(self, shape: ShapeConfig, *, num_clients: int = 0,
-                    dtype=jnp.float32) -> Dict[str, Any]:
-        """ShapeDtypeStruct stand-ins for every model input of this cell."""
-        cfg = self.cfg
-        sds = jax.ShapeDtypeStruct
-        s, b = shape.seq_len, shape.global_batch
-
-        def tok_shape(extra: Tuple[int, ...]):
-            if num_clients:
-                return (num_clients, b // num_clients) + extra
-            return (b,) + extra
-
-        specs: Dict[str, Any] = {}
-        if shape.kind == "train":
-            specs["tokens"] = sds(tok_shape((s,)), jnp.int32)
-            specs["labels"] = sds(tok_shape((s,)), jnp.int32)
-            specs["loss_mask"] = sds(tok_shape((s,)), jnp.float32)
-        elif shape.kind == "prefill":
-            specs["tokens"] = sds((b, s), jnp.int32)
-        else:  # decode
-            specs["tokens"] = sds((b, 1), jnp.int32)
-        if cfg.family == "vlm" and cfg.frontend_prefix_len:
-            if shape.kind in ("train", "prefill"):
-                specs["prefix"] = sds(
-                    tok_shape((cfg.frontend_prefix_len, cfg.d_model))
-                    if shape.kind == "train"
-                    else (b, cfg.frontend_prefix_len, cfg.d_model), dtype)
-        if cfg.family == "audio" and shape.kind in ("train", "prefill"):
-            enc_shape = (tok_shape((cfg.encoder_seq_len, cfg.d_model))
-                         if shape.kind == "train"
-                         else (b, cfg.encoder_seq_len, cfg.d_model))
-            specs["frames"] = sds(enc_shape, dtype)
-        return specs
-
-
-@functools.lru_cache(maxsize=None)
-def _cached_model(arch_key: str) -> Model:
-    from repro.configs import get_config
-    return Model(get_config(arch_key))
 
 
 def build_model(arch: ArchConfig, *, unroll: bool = False) -> Model:

@@ -1,7 +1,6 @@
 from repro.runtime.sharding import (  # noqa: F401
-    batch_specs, cache_specs, constrain_client_batch, constrain_state,
-    fit_spec, param_specs, adapter_specs, shardings_for, state_client_axis,
-    state_specs,
+    cache_specs, constrain_client_batch, constrain_state, fit_spec,
+    param_specs, shardings_for, state_client_axis, state_specs,
 )
 from repro.runtime.straggler import (  # noqa: F401
     PHASES, SpeedModel, deadline_survivors, pipelined_makespan,

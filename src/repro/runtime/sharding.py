@@ -13,7 +13,8 @@ Scheme (DESIGN.md §5):
     batch=1 long-context decode).
 
 All functions take the *abstract* tree (ShapeDtypeStructs ok) — nothing
-here touches real device memory, which is what the dry-run requires.
+here touches real device memory, so specs can be built before the
+arrays exist.
 """
 
 from __future__ import annotations
@@ -126,7 +127,7 @@ def constrain_client_batch(batch, mesh: Optional[Mesh], *,
     """with_sharding_constraint a client-stacked batch ((N, B, S) leaves,
     or (K, N, B, S) with step_axis=True under the local-steps engine):
     clients over the data axis, per-client batch over the remaining FSDP
-    axes (batch_specs' client_dim=True rule)."""
+    axes."""
     if mesh is None:
         return batch
     rest = tuple(a for a in FSDP_AXES if a != CLIENT_AXIS)
@@ -230,36 +231,6 @@ def param_specs(params, mesh: Mesh):
         logical = _leaf_spec_for_path(keys, np.ndim(leaf))
         specs.append(fit_spec(np.shape(leaf), logical, mesh))
     return jax.tree.unflatten(treedef, specs)
-
-
-def adapter_specs(adapters, mesh: Mesh, *, client_stacked: bool):
-    """Adapters: {group:{target:{"A","B"}}}.
-
-    Server adapters ((Lg, din, r)) are replicated (tiny); client-stacked
-    adapters ((Lg, N, din, r)) shard N over the client/data axis."""
-    def spec_of(leaf):
-        nd = np.ndim(leaf)
-        if client_stacked and nd >= 3:
-            logical = (None, CLIENT_AXIS) + (None,) * (nd - 2)
-        else:
-            logical = (None,) * nd
-        return fit_spec(np.shape(leaf), logical, mesh)
-
-    return jax.tree.map(spec_of, adapters)
-
-
-def batch_specs(batch, mesh: Mesh, *, client_dim: bool):
-    """tokens/labels/mask ([N,]B,S[,d]) and frames/prefix embeddings."""
-    def spec_of(leaf):
-        nd = np.ndim(leaf)
-        if client_dim:
-            rest = tuple(a for a in FSDP_AXES if a != CLIENT_AXIS)
-            logical = (CLIENT_AXIS, rest) + (None,) * (nd - 2)
-        else:
-            logical = (FSDP_AXES,) + (None,) * (nd - 1)
-        return fit_spec(np.shape(leaf), logical, mesh)
-
-    return jax.tree.map(spec_of, batch)
 
 
 def cache_specs(cache, mesh: Mesh):

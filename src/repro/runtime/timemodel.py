@@ -37,8 +37,8 @@ hardware the declared SpeedModel mis-describes.
 The `model` SpeedModel defaults to the `clock` object itself (analytic
 == the clock's own stationary view, bitwise with the pre-refactor
 pricer).  Passing a model drawn from a different seed deliberately
-mis-specifies the controller's belief — the testbed `bench_adaptive`
-uses to show `measured` beating `analytic` on time-to-target.
+mis-specifies the controller's belief — the testbed in which
+`measured` corrects what `analytic` cannot (tests/test_timemodel.py).
 
 Measured state is keyed by population id (`SpeedModel._pids`), so the
 EWMA survives cohort churn, and round-trips through checkpoint metadata

@@ -2,5 +2,5 @@ import os
 
 # Kernel tests opt into Pallas interpret mode per-module via the
 # REPRO_PALLAS_INTERPRET env var; everything else runs the jnp reference
-# paths on the single CPU device (the dry-run owns the 512-device config).
+# paths on the single CPU device.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")

@@ -320,6 +320,47 @@ def test_co_controller_trains_and_stays_in_buckets():
     assert np.isfinite(hist[-1]["loss"])
 
 
+def test_co_controller_beats_accuracy_on_simulated_clock():
+    """Same straggler fleet, same rounds, deterministic clock: the
+    co-controller's (cut, rank, compressor) choices take less simulated
+    time than the accuracy-only rule's, and reach the shared target loss
+    (the weaker lane's final loss) no later.  It moves the rank and
+    compressor, not just the cut."""
+    arch = reduced(get_config("gpt2-small"), layers=4, d_model=32,
+                   vocab=256, seq_len=16, batch=2)
+    arch = arch.replace(
+        train=dataclasses.replace(arch.train, lr_client=3e-3,
+                                  lr_server=3e-3),
+        data=dataclasses.replace(arch.data, num_clients=3,
+                                 partition="iid"),
+        split=dataclasses.replace(arch.split, cut_layer=1))
+    kw = dict(num_samples=48, eval_samples=16, straggler_sim=True,
+              jitter_sigma=0.0)
+    hist = {
+        c: SplitFTSystem(arch, SystemConfig(controller=c, **kw, **extra),
+                         seed=0).run(4, log_every=0)
+        for c, extra in (
+            ("accuracy", {}),
+            ("co", dict(rank_buckets=(1, 2, 4),
+                        compressor_buckets=("none", "int8", "topk"))))}
+
+    def time_to(hs, target):
+        t = 0.0
+        for h in hs:
+            t += h["sim_time"]
+            if h["loss"] <= target:
+                break
+        return t
+
+    target = max(hs[-1]["loss"] for hs in hist.values())
+    total = {c: sum(h["sim_time"] for h in hs) for c, hs in hist.items()}
+    assert total["co"] < total["accuracy"]
+    assert time_to(hist["co"], target) <= time_to(hist["accuracy"], target)
+    last = hist["co"][-1]          # starts at (r_cut, "none" = choice 0)
+    assert np.any(last["rank_cut"] != arch.lora.r_cut)
+    assert np.any(last["smashed_choice"] != 0)
+
+
 def test_co_controller_checkpoint_roundtrip(tmp_path):
     cfg = SystemConfig(controller="co", rank_buckets=(1, 2, 4),
                        compressor_buckets=("none", "int8"),
@@ -338,10 +379,12 @@ def test_co_controller_checkpoint_roundtrip(tmp_path):
 
 
 def test_co_controller_rejects_smashed_ef():
-    cfg = SystemConfig(controller="co", smashed_compress="topk",
-                       smashed_ef=True, **SYS)
+    cfg = SystemConfig(controller="co", smashed_ef=True, **SYS)
+    arch = small_arch()
+    arch = arch.replace(split=dataclasses.replace(arch.split,
+                                                  smashed_compress="topk"))
     with pytest.raises(ValueError, match="error feedback"):
-        SplitFTSystem(small_arch(), cfg, seed=0)
+        SplitFTSystem(arch, cfg, seed=0)
 
 
 def test_co_controller_async_scheduler_composes():

@@ -63,8 +63,8 @@ def test_dotted_module_references_resolve():
 
 def test_cli_flags_exist():
     """Every `--flag` the docs mention must be a real option of
-    repro.launch.train's, repro.launch.serve's or chip_smoke.py's parser
-    (or benchmarks.run's --dry-run)."""
+    repro.launch.train's, repro.launch.serve's or chip_smoke.py's
+    parser."""
     import importlib.util
 
     from repro.launch.serve import build_parser as serve_parser
@@ -73,7 +73,7 @@ def test_cli_flags_exist():
         "chip_smoke", REPO / "chip_smoke.py")
     chip_smoke = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(chip_smoke)
-    known = {"--dry-run"}
+    known = set()
     for parser in (train_parser(), serve_parser(), chip_smoke._parser()):
         for act in parser._actions:
             known.update(act.option_strings)
@@ -98,7 +98,7 @@ def test_documented_co_invocation_parses():
 
 def test_knob_table_matches_config():
     """The README knob table's config names must be real SystemConfig
-    fields and SplitConfig fields."""
+    fields, and live there only: a run setting has one home."""
     import dataclasses
 
     from repro.config.base import SplitConfig
@@ -106,6 +106,6 @@ def test_knob_table_matches_config():
     sys_fields = {f.name for f in dataclasses.fields(SystemConfig)}
     split_fields = {f.name for f in dataclasses.fields(SplitConfig)}
     for knob in ("controller", "rank_buckets", "compressor_buckets",
-                 "acc_dead_band", "min_gain"):
+                 "acc_dead_band", "min_gain", "continuous_topk"):
         assert knob in sys_fields, f"SystemConfig.{knob} missing"
-        assert knob in split_fields, f"SplitConfig.{knob} missing"
+        assert knob not in split_fields, f"SplitConfig.{knob} duplicates it"

@@ -33,9 +33,11 @@ from repro.runtime import sharding as rules
 from repro.runtime.population import CohortSampler, PopulationStore
 
 
-def small_arch(layers=4):
-    return reduced(get_config("gpt2-small"), layers=layers, d_model=64,
+def small_arch(layers=4, population=0):
+    arch = reduced(get_config("gpt2-small"), layers=layers, d_model=64,
                    vocab=512, seq_len=32, batch=2)
+    return arch.replace(data=dataclasses.replace(arch.data,
+                                                 population=population))
 
 
 SYS = dict(num_samples=80, eval_samples=16)
@@ -190,8 +192,8 @@ def test_population_equals_cohort_reproduces_fleet_bitwise():
     arch = small_arch()
     fleet = SplitFTSystem(arch, SystemConfig(**SYS), seed=0)
     fleet.run(3, log_every=0)
-    pop = SplitFTSystem(arch, SystemConfig(
-        population=arch.data.num_clients, **SYS), seed=0)
+    pop = SplitFTSystem(small_arch(population=arch.data.num_clients),
+                        SystemConfig(**SYS), seed=0)
     pop.run(3, log_every=0)
     assert tree_equal(fleet.state, pop.state)
     assert [r["loss"] for r in fleet.history] == \
@@ -199,8 +201,8 @@ def test_population_equals_cohort_reproduces_fleet_bitwise():
 
 
 def test_population_sampling_trains_distinct_pids():
-    arch = small_arch()
-    sys = SplitFTSystem(arch, SystemConfig(population=12, **SYS), seed=0)
+    arch = small_arch(population=12)
+    sys = SplitFTSystem(arch, SystemConfig(**SYS), seed=0)
     sys.run(4, log_every=0)
     # 4 cohorts of 3 from 12 pids: more slots materialized than one cohort
     assert len(sys.store) > arch.data.num_clients
@@ -208,9 +210,9 @@ def test_population_sampling_trains_distinct_pids():
 
 
 def test_population_async_runs():
-    arch = small_arch()
+    arch = small_arch(population=12)
     sys = SplitFTSystem(arch, SystemConfig(
-        population=12, scheduler="async", buffer_size=2,
+        scheduler="async", buffer_size=2,
         straggler_sim=True, **SYS), seed=0)
     sys.run(3, log_every=0)
     assert len(sys.history) == 3
@@ -276,9 +278,9 @@ def test_hierarchical_reduces_charged_server_phase_time():
     """With a finite server ingest link, >= 4 edge groups strictly cut
     the charged adapter-sync+ingest phase vs flat (the edges pre-reduce,
     so the server ingests E adapters instead of N)."""
-    arch = small_arch()
+    arch = small_arch(population=12)
     kw = dict(straggler_sim=True, scheduler="sync",
-              server_ingest_bw=1e6, population=12, **SYS)
+              server_ingest_bw=1e6, **SYS)
     flat = SplitFTSystem(arch, SystemConfig(**kw), seed=0)
     flat.run(2, log_every=0)
     hier = SplitFTSystem(arch, SystemConfig(edge_groups=4, **kw), seed=0)
@@ -364,13 +366,11 @@ def test_server_step_norm_changes_heterogeneous_local_steps():
 
 
 def test_population_checkpoint_resume_bitwise():
-    arch = small_arch()
-    straight = SplitFTSystem(arch, SystemConfig(population=12, **SYS),
-                             seed=0)
+    arch = small_arch(population=12)
+    straight = SplitFTSystem(arch, SystemConfig(**SYS), seed=0)
     straight.run(4, log_every=0)
     with tempfile.TemporaryDirectory() as td:
-        kw = dict(population=12, checkpoint_dir=td, checkpoint_every=2,
-                  **SYS)
+        kw = dict(checkpoint_dir=td, checkpoint_every=2, **SYS)
         first = SplitFTSystem(arch, SystemConfig(**kw), seed=0)
         first.run(2, log_every=0)
         resumed = SplitFTSystem(arch, SystemConfig(**kw), seed=0)
@@ -385,9 +385,9 @@ def test_population_mismatch_raises_loudly():
     arch = small_arch()
     with tempfile.TemporaryDirectory() as td:
         kw = dict(checkpoint_dir=td, checkpoint_every=2, **SYS)
-        SplitFTSystem(arch, SystemConfig(population=12, **kw),
+        SplitFTSystem(small_arch(population=12), SystemConfig(**kw),
                       seed=0).run(2, log_every=0)
-        bad = SplitFTSystem(arch, SystemConfig(population=24, **kw),
+        bad = SplitFTSystem(small_arch(population=24), SystemConfig(**kw),
                             seed=0)
         with pytest.raises(ValueError, match="population"):
             bad.restore()

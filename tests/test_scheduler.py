@@ -19,11 +19,13 @@ from repro.models.model import build_model
 from repro.runtime.straggler import SpeedModel, local_step_budgets
 
 
-def small_arch(layers=4, lr=3e-3):
+def small_arch(layers=4, lr=3e-3, **split):
+    """split: SplitConfig fields (the smashed compressor) to override."""
     arch = reduced(get_config("gpt2-small"), layers=layers, d_model=64,
                    vocab=512, seq_len=64, batch=4)
-    return arch.replace(train=dataclasses.replace(
-        arch.train, lr_client=lr, lr_server=lr))
+    return arch.replace(
+        train=dataclasses.replace(arch.train, lr_client=lr, lr_server=lr),
+        split=dataclasses.replace(arch.split, **split))
 
 
 SYS = dict(num_samples=150, eval_samples=32)
@@ -314,9 +316,9 @@ def test_deadline_comm_record_skips_dropped_clients():
 
 
 def test_smashed_ef_requires_topk():
-    cfg = SystemConfig(smashed_compress="int8", smashed_ef=True, **SYS)
+    cfg = SystemConfig(smashed_ef=True, **SYS)
     with pytest.raises(ValueError, match="topk"):
-        SplitFTSystem(small_arch(), cfg, seed=0)
+        SplitFTSystem(small_arch(smashed_compress="int8"), cfg, seed=0)
 
 
 def test_restore_with_different_scheduler_raises():
@@ -334,25 +336,23 @@ def test_restore_with_different_scheduler_raises():
 def test_restore_with_different_state_template_raises():
     """Same scheduler, but the smashed-EF leaf vanished: restore must
     diagnose the template change, not silently restart from round 0."""
-    arch = small_arch()
+    arch = small_arch(smashed_compress="topk", smashed_topk_frac=0.25)
     with tempfile.TemporaryDirectory() as d:
-        cfg = SystemConfig(checkpoint_dir=d, checkpoint_every=2,
-                           smashed_compress="topk",
-                           smashed_topk_frac=0.25, **SYS)
+        cfg = SystemConfig(checkpoint_dir=d, checkpoint_every=2, **SYS)
         s1 = SplitFTSystem(arch, cfg, seed=0)
         s1.run(2, log_every=0)
-        cfg2 = dataclasses.replace(cfg, smashed_compress="none")
-        s2 = SplitFTSystem(arch, cfg2, seed=0)
+        arch2 = arch.replace(split=dataclasses.replace(
+            arch.split, smashed_compress="none"))
+        s2 = SplitFTSystem(arch2, cfg, seed=0)
         with pytest.raises(ValueError, match="template"):
             s2.restore()
 
 
 def test_checkpoint_roundtrips_scheduler_state():
     """step budgets + smashed EF residuals survive save/restore exactly."""
-    arch = small_arch()
+    arch = small_arch(smashed_compress="topk", smashed_topk_frac=0.25)
     with tempfile.TemporaryDirectory() as d:
         cfg = SystemConfig(scheduler="local_steps", max_local_steps=3,
-                           smashed_compress="topk", smashed_topk_frac=0.25,
                            checkpoint_dir=d, checkpoint_every=2, **SYS)
         s1 = SplitFTSystem(arch, cfg, seed=0)
         s1.run(4, log_every=0)

@@ -1,8 +1,8 @@
 """Sharding-rule unit tests + 1-device mesh execution of the sharded path.
 
-The 512-device production mesh is exercised by launch/dryrun.py (which owns
-the XLA_FLAGS device-count override); here we verify the *rules* and that
-the constrained code path runs on a real (1,1) mesh.
+Here we verify the *rules* and that the constrained code path runs on a
+real (1,1) mesh; tests/test_chip_compile.py compiles the round for a
+described four-chip mesh.
 """
 
 import jax

@@ -12,7 +12,7 @@ import pytest
 
 from repro.checkpoint import CheckpointManager, load_checkpoint, \
     save_checkpoint
-from repro.config import reduced
+from repro.config import DataConfig, SplitConfig, reduced
 from repro.configs import get_config
 from repro.core import rounds
 from repro.core.system import SplitFTSystem, SystemConfig
@@ -28,6 +28,61 @@ def small_arch(layers=4, lr=3e-3):
 
 
 SYS = dict(num_samples=150, eval_samples=32)
+
+
+# ---------------------------------------------------------------------------
+# one home per setting: the arch decides, SystemConfig drives the run
+
+
+@pytest.mark.parametrize("a,b", [(SystemConfig, SplitConfig),
+                                 (SystemConfig, DataConfig),
+                                 (SplitConfig, DataConfig)],
+                         ids=["system-split", "system-data", "split-data"])
+def test_no_setting_has_two_homes(a, b):
+    names = {f.name for f in dataclasses.fields(a)}
+    assert not names & {f.name for f in dataclasses.fields(b)}
+
+
+# the chip benchmark's train.paper flags
+BENCH_FLAGS = ["--smashed-compress", "int8", "--scheduler", "sync",
+          "--controller", "accuracy"]
+
+
+@pytest.mark.parametrize("flags,want", [
+    ([], dict(smashed="none", sched="sync", adaptive=True)),
+    (BENCH_FLAGS + ["--adaptive"],
+     dict(smashed="int8", sched="sync", adaptive=True)),
+    (BENCH_FLAGS + ["--no-adaptive"],
+     dict(smashed="int8", sched="sync", adaptive=False)),
+    (BENCH_FLAGS + ["--adaptive", "--population", "6"],
+     dict(smashed="int8", sched="sync", adaptive=True, population=6)),
+    (["--smashed-compress", "int8", "--scheduler", "async", "--adaptive",
+      "--buffer-size", "5"],
+     dict(smashed="int8", sched="async", adaptive=True, buffer=3)),
+    (BENCH_FLAGS + ["--adaptive", "--edge-groups", "2"],
+     dict(smashed="int8", sched="sync", adaptive=True, edges=2)),
+    (["--straggler-sim"], dict(smashed="none", sched="deadline",
+                               adaptive=True)),
+], ids=["defaults", "bench", "bench-no-adaptive", "population",
+        "async-buffer-clamped", "edge-groups", "straggler-legacy"])
+def test_build_system_maps_each_flag_to_its_home(tmp_path, flags, want):
+    from repro.launch import train as ltrain
+    args = ltrain.build_parser().parse_args(
+        ["--arch", "gpt2-small", "--reduced", "--samples", "40",
+         "--out", str(tmp_path)] + flags)
+    s = ltrain.build_system(args)
+    assert s.smashed_compress == s.arch.split.smashed_compress \
+        == want["smashed"]
+    assert s.comp_buckets == (want["smashed"],)
+    assert s.scheduler.name == want["sched"]
+    assert s.sys.controller == "accuracy"
+    assert s.sys.adaptive is want["adaptive"]
+    assert s.population == s.arch.data.population \
+        == want.get("population", 0)
+    assert s.num_edges == want.get("edges", 1)
+    if "buffer" in want:             # clamped to the 3 reduced clients
+        assert s.scheduler.buffer_size == want["buffer"]
+    assert s.rank_buckets == (s.arch.lora.r_cut,)
 
 
 def test_rounds_run_and_learn():
@@ -56,9 +111,7 @@ def test_adaptive_cuts_move_and_stay_in_buckets():
 
 def test_fixed_split_baseline_keeps_cuts():
     arch = small_arch()
-    arch = arch.replace(split=dataclasses.replace(arch.split,
-                                                  adaptive=False))
-    sys_ = SplitFTSystem(arch, SystemConfig(**SYS), seed=0)
+    sys_ = SplitFTSystem(arch, SystemConfig(adaptive=False, **SYS), seed=0)
     hist = sys_.run(5, log_every=0)
     for h in hist:
         assert h["cuts"].tolist() == [arch.split.cut_layer] * 3

@@ -233,8 +233,9 @@ def test_measured_state_keyed_by_pid_across_cohort_churn():
     cohort slot — each pid's learned ratio equals its own model/clock
     draw ratio no matter which slot (or round) it was observed in."""
     arch = small_arch()
+    arch = arch.replace(data=dataclasses.replace(arch.data, population=12))
     sys_ = SplitFTSystem(arch, SystemConfig(
-        population=12, straggler_sim=True, adaptive=False,
+        straggler_sim=True, adaptive=False,
         scheduler="sync", time_source="measured", jitter_sigma=0.0,
         model_seed=7, **SYS), seed=0)
     sys_.run(4, log_every=0)

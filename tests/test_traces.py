@@ -214,6 +214,45 @@ def test_trace_changes_clock_and_prices_controller_window():
     assert not np.array_equal(p_now, p_later)
 
 
+def tiny_arch(**data):
+    """4 layers at d_model 32, 3 clients: the smallest arch on which the
+    schedulers' simulated clocks separate."""
+    arch = reduced(get_config("gpt2-small"), layers=4, d_model=32,
+                   vocab=256, seq_len=16, batch=2)
+    return arch.replace(
+        train=dataclasses.replace(arch.train, lr_client=3e-3,
+                                  lr_server=3e-3),
+        data=dataclasses.replace(arch.data, num_clients=3, **data))
+
+
+def time_to_target(hist, target):
+    """Simulated clock at the first round whose loss reaches target."""
+    return next(h["sim_clock"] for h in hist if h["loss"] <= target)
+
+
+@pytest.mark.parametrize("alpha", [100.0, 0.3])
+@pytest.mark.parametrize("compress", ["none", "int8"])
+def test_diurnal_async_beats_sync_time_to_target(alpha, compress):
+    """Under a diurnal trace the sync barrier waits for whoever is in its
+    trough; the async buffer flush does not.  The target is the weaker
+    lane's best loss, so both lanes reach it; the deterministic clock
+    (jitter_sigma=0) makes the comparison exact."""
+    arch = tiny_arch(partition="dirichlet", alpha=alpha)
+    arch = arch.replace(split=dataclasses.replace(
+        arch.split, smashed_compress=compress))
+    kw = dict(num_samples=48, eval_samples=16, straggler_sim=True,
+              jitter_sigma=0.0,
+              trace_gen="diurnal:amp=1.0,period=240,step=20")
+    hist = {sched: SplitFTSystem(arch, SystemConfig(scheduler=sched, **kw,
+                                                    **extra),
+                                 seed=0).run(2, log_every=0)
+            for sched, extra in (("sync", {}),
+                                 ("async", dict(buffer_size=2)))}
+    target = max(min(h["loss"] for h in hs) for hs in hist.values())
+    assert time_to_target(hist["async"], target) \
+        < time_to_target(hist["sync"], target)
+
+
 def test_file_trace_availability_masks_barrier_round(tmp_path):
     """Client 0 is never available: every sync round runs without it,
     and an all-down first window makes the round WAIT (clock advances to
